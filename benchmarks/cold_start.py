@@ -211,17 +211,24 @@ def _spawn(args, extra_env=None):
     return json.loads(line[len("RESULT"):])
 
 
-def run():
+def run() -> bool:
+    """Every phase in its own child; this process never touches JAX.
+    True when every phase ran — a failed child is reported and fails
+    the run, it is never skipped."""
     with tempfile.TemporaryDirectory(prefix="repro-cold-store-") as store:
-        _run(store)
+        failures = _run(store)
+    for msg in failures:
+        print(f"cold_start: FAIL {msg}")
+    return not failures
 
 
-def _run(store: str) -> None:
+def _run(store: str) -> list:
+    failures = []
     calib_env = {"REPRO_CALIB_CACHE": os.path.join(store, "calib.json")}
     try:
         _spawn(["--child", "profile"], calib_env)
     except (RuntimeError, subprocess.TimeoutExpired, IndexError) as e:
-        print(f"# cold_start: profile warm failed ({e})")
+        failures.append(f"profile warm: {e}")
     for kernel in KERNELS:
         with tempfile.TemporaryDirectory(prefix="repro-cold-") as d:
             try:
@@ -233,7 +240,7 @@ def _run(store: str) -> None:
                                "--rival-cfg", json.dumps(topk["cfg"])],
                               calib_env)
             except (RuntimeError, subprocess.TimeoutExpired, IndexError) as e:
-                print(f"# cold_start/{kernel}: SKIP ({e})")
+                failures.append(f"{kernel}: {e}")
                 continue
         speedup = full["t_search"] / max(topk["t_search"], 1e-9)
         match = topk["cfg"] == full["cfg"]
@@ -259,8 +266,8 @@ def _run(store: str) -> None:
             a = _spawn(["--child", "hybrid", "--phase", "1", "--tmpdir", d])
             b = _spawn(["--child", "hybrid", "--phase", "2", "--tmpdir", d])
         except (RuntimeError, subprocess.TimeoutExpired, IndexError) as e:
-            print(f"# cold_start/hybrid: SKIP ({e})")
-            return
+            failures.append(f"hybrid: {e}")
+            return failures
     cu = a["chunk_units"]
     groups = set(a["plan"]) | set(b["plan"])
     max_delta = max(abs(a["plan"].get(g, 0) - b["plan"].get(g, 0))
@@ -270,6 +277,7 @@ def _run(store: str) -> None:
           f"|plan_match={max_delta <= cu}"
           f"|max_plan_delta_units={max_delta}"
           f"|cold_probes={a['probes_first_call']}")
+    return failures
 
 
 def main():
@@ -288,7 +296,7 @@ def main():
     elif args.child == "profile":
         _child_profile()
     else:
-        run()
+        sys.exit(0 if run() else 1)
 
 
 if __name__ == "__main__":

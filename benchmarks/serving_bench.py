@@ -49,6 +49,11 @@ runs a reduced trace plus the two-process persisted-calibration check
 PR 3's cold-start contract at the serving layer), exiting non-zero on
 any violation.
 
+One process per chip: the phases that start JAX processes (the
+two-process and LM cold-start checks, the fleet) run first, while this
+process is still off JAX, and each fleet worker owns one chip
+(``transport.chip_env``) while chips last; the rest run on the CPU.
+
 Rows land in BENCH_serving.json (and BENCH_history.jsonl via
 ``run.py --json``); ``regress.py`` gates serving/* p95 and throughput
 rows at a looser threshold (queueing tails are noisier than kernel
@@ -60,11 +65,13 @@ microbenches).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import subprocess
 import sys
 import time
+from typing import Optional
 
 import numpy as np
 
@@ -640,14 +647,44 @@ def _fleet_env(store_dir, extra=None):
     return env
 
 
+@functools.lru_cache(maxsize=None)
+def _host_chips() -> int:
+    """TPU chips on this host, counted once by a child process (this
+    process stays off JAX while it spawns workers that need the chip)."""
+    res = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); "
+         "print(len(d) if d[0].platform == 'tpu' else 0)"],
+        capture_output=True, text=True, timeout=300, cwd=_ROOT)
+    if res.returncode != 0:
+        raise RuntimeError("device probe failed:\n" + res.stderr)
+    return int(res.stdout.strip().splitlines()[-1])
+
+
 def _fleet_router(k, store_dir, hb_s=0.2, hb_timeout_s=1.0,
                   env_extra=None):
+    """K worker processes: one chip each while chips last, the rest on
+    the CPU (``transport.chip_env``)."""
     from repro.serve.router import Router
-    from repro.serve.transport import ProcWorker
+    from repro.serve.transport import ProcWorker, chip_env
 
-    workers = [ProcWorker(f"fw{i}", env=_fleet_env(store_dir, env_extra),
+    n_chips = _host_chips()
+    workers = [ProcWorker(f"fw{i}",
+                          env={**_fleet_env(store_dir, env_extra),
+                               **chip_env(i if i < n_chips else None)},
                           hb_interval_s=hb_s) for i in range(k)]
     return Router(workers, hb_timeout_s=hb_timeout_s).start()
+
+
+def _fleet_service_time(router, mix, reps: int = 3) -> float:
+    """Mean one-request service time through a warm fleet, requests
+    sent one at a time (the fleet's analogue of ``_warm_and_measure``:
+    this process never runs the workloads itself)."""
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        for wl, payload in mix:
+            router.submit(wl, payload).result(timeout=560)
+    return (time.perf_counter() - t0) / (reps * len(mix))
 
 
 def _broadcast_warm(router, mix, timeout_s=560.0):
@@ -753,7 +790,10 @@ def fleet_cold_join_check(mix, verbose: bool = True):
         probes_a = stats_a.get("fw0", {}).get("probe_runs", -1)
         ra.shutdown(timeout=60)       # worker exit flushes the store
 
-        cold = ProcWorker("coldw", env=_fleet_env(tmp, extra),
+        from repro.serve.transport import chip_env
+        cold = ProcWorker("coldw", env={**_fleet_env(tmp, extra),
+                                        **chip_env(0 if _host_chips()
+                                                   else None)},
                           hb_interval_s=0.2)
         rb = Router([cold], hb_timeout_s=5.0).start()
         for f in [rb.submit(wl, p) for wl, p in mix]:
@@ -792,21 +832,8 @@ def run_fleet(smoke: bool, mix=None, trace_path=None):
 
     mix = mix or _mix(smoke)
     k = max(int(_env_float("REPRO_FLEET_WORKERS", 2)), 2)
-    t_service, _ = _warm_and_measure(mix, measure_capacity=False)
-    base_rate = 1.0 / max(t_service, 1e-6)
-
-    # 0.9x ONE lane's rate against a K-worker fleet: each survivor can
-    # absorb the dead worker's range without saturating — goodput
-    # through the outage is the row, not raw capacity
-    rate = 0.9 * base_rate
     n = 48 if smoke else 80
-    trace = make_trace(rate, n, mix, seed=29)
-    span = trace[-1][0]
-    # a sub-second smoke trace would script the kill before the fleet
-    # finishes warming its pipes — floor the fault offsets instead of
-    # stretching the trace
-    t_kill = max(0.1, span * 0.35)
-    t_restart = max(t_kill + 0.5, span * 0.75)
+    trace = None
 
     dropped = hung = 0
     base = chaos = None
@@ -816,6 +843,19 @@ def run_fleet(smoke: bool, mix=None, trace_path=None):
         store = tempfile.mkdtemp(prefix="repro-fleet-")
         rb = _fleet_router(k, store)
         _broadcast_warm(rb, mix)
+        if trace is None:
+            # 0.9x ONE lane's rate against a K-worker fleet: each
+            # survivor can absorb the dead worker's range without
+            # saturating — goodput through the outage is the row, not
+            # raw capacity
+            rate = 0.9 / max(_fleet_service_time(rb, mix), 1e-6)
+            trace = make_trace(rate, n, mix, seed=29)
+            span = trace[-1][0]
+            # a sub-second smoke trace would script the kill before the
+            # fleet finishes warming its pipes — floor the fault offsets
+            # instead of stretching the trace
+            t_kill = max(0.1, span * 0.35)
+            t_restart = max(t_kill + 0.5, span * 0.75)
         b = _replay_fleet(rb, trace)
         rb.shutdown(timeout=60)
 
@@ -976,7 +1016,7 @@ def lm_cold_start_check(verbose: bool = True):
     return out
 
 
-def run_lm(smoke: bool, cold_check: bool = True):
+def run_lm(smoke: bool, cold: Optional[dict] = None):
     """Continuous batching vs the monolithic LM adapter on the SAME
     open-loop Poisson trace: at a saturating arrival rate the step
     quantum stacks live decodes into one slot-batched call (throughput
@@ -1076,8 +1116,7 @@ def run_lm(smoke: bool, cold_check: bool = True):
     if n_dev >= 2 and ratio_sat < 1.5:
         failures.append(f"LM continuous throughput only {ratio_sat:.2f}x "
                         f"monolithic at saturating rate (target >=1.5x)")
-    if cold_check:
-        cold = lm_cold_start_check()
+    if cold is not None:
         results["cold_start"] = cold
         if cold["probe_runs"] != 0:
             failures.append(f"LM engine cold start paid "
@@ -1091,6 +1130,16 @@ def run(smoke: bool = False, json_out: bool = False,
         trace_path: str = ""):
     mix = _mix(smoke)
     n_requests = n_requests or (96 if smoke else 90)
+    # Phases that start JAX processes run first, while this process is
+    # still off JAX: a parent that has touched the chip holds it, and
+    # its children could not open it.
+    probes_b = lm_cold = None
+    if two_process:
+        _, probes_b = two_process_check()
+        lm_cold = lm_cold_start_check()
+    # --- fleet availability: kill 1 of K worker processes ---
+    fleet_rows, fleet_results, fleet_failures = run_fleet(smoke, mix=mix)
+
     t_service, capacity = _warm_and_measure(mix)
     base_rate = 1.0 / max(t_service, 1e-6)      # one lane's capacity
     # 0.5x/0.9x: both policies keep up (par is the pass bar there);
@@ -1237,15 +1286,12 @@ def run(smoke: bool = False, json_out: bool = False,
     results["chaos"] = chaos_results
     dropped_total += chaos_results["dropped_without_rejection"]
 
-    # --- fleet availability: kill 1 of K worker processes (PR 8) ---
-    fleet_rows, fleet_results, fleet_failures = run_fleet(smoke, mix=mix)
     rows += fleet_rows
     results["fleet"] = fleet_results
     dropped_total += fleet_results["dropped_without_rejection"]
 
     # --- LM continuous batching vs monolithic (PR 6 tentpole) ---
-    lm_rows, lm_results, lm_failures = run_lm(smoke,
-                                              cold_check=two_process)
+    lm_rows, lm_results, lm_failures = run_lm(smoke, cold=lm_cold)
     rows += lm_rows
     results["lm"] = lm_results
     dropped_total += lm_results["dropped_without_rejection"]
@@ -1272,17 +1318,16 @@ def run(smoke: bool = False, json_out: bool = False,
         for r in scn_results]
     results["dropped_without_rejection"] = dropped_total
 
-    probes_b = None
-    if two_process:
-        _, probes_b = two_process_check()
+    if probes_b is not None:
         results["cold_probe_runs_procB"] = probes_b
     for row in rows:
         print(row)
 
     if json_out:
         import jax
-        meta = {"backend": jax.default_backend(),
-                "n_devices": len(jax.devices()), "smoke": smoke}
+        d = jax.devices()
+        meta = {"platform": d[0].platform, "device_kind": d[0].device_kind,
+                "n_devices": len(d), "smoke": smoke}
         with open(os.path.join(_ROOT, "BENCH_serving.json"), "w") as f:
             json.dump({"meta": meta, "results": results}, f, indent=1)
         print("# wrote BENCH_serving.json")
@@ -1368,6 +1413,8 @@ if __name__ == "__main__":
                          "cross-worker chaos trace, plus a gate that "
                          "one request crossed the worker death)")
     args = ap.parse_args()
+    from repro.core import compile_cache
+    compile_cache.enable()
     if args.chaos:
         c_rows, _, c_failures = run_chaos(smoke=args.smoke)
         for row in c_rows:

@@ -9,8 +9,6 @@ from __future__ import annotations
 import importlib
 import time
 
-from repro.core.hybrid_executor import HybridExecutor
-
 # benchmark-scale inputs (largest that run in reasonable time here;
 # the paper uses the largest inputs that fit GPU memory)
 SIZES = dict(
@@ -36,6 +34,7 @@ PAPER_GAIN = {
 
 
 def run(csv: bool = True):
+    from repro.core.hybrid_executor import HybridExecutor
     from repro.workloads import ALL_WORKLOADS
     rows = []
     results = {}

@@ -6,7 +6,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ParallelConfig
-from repro.core import HybridExecutor, TaskGraph, plan_work
+from repro.core.hybrid_executor import HybridExecutor
+from repro.core.task_graph import TaskGraph
+from repro.core.work_sharing import plan_work
 from repro.models import model_zoo, param
 from repro.workloads import conv
 

@@ -7,17 +7,10 @@
 - hybrid_executor: executes work-shared plans over JAX device groups
 - host_offload:   LUT/PRNG/pipeline host tasks (paper §4.6-§4.8)
 - metrics:        gain & idle-time accounting (paper §5.1)
+- device:         the device a call runs on (one notion of lane identity)
+- compile_cache:  JAX's persistent compile cache at a fixed path
+
+Submodules are imported by name: importing ``repro.core`` itself pulls
+in nothing, so a process that only routes requests (``serve.router``)
+never imports JAX.
 """
-from repro.core.work_sharing import (WorkPlan, integer_shares, paper_split,
-                                     plan_work, proportional_shares,
-                                     refine_split)
-from repro.core.task_graph import Schedule, Task, TaskGraph
-from repro.core.calibration import (CalibrationCache, ThroughputTracker,
-                                    clear_calibration_cache,
-                                    get_calibration_cache)
-from repro.core.async_executor import (AsyncChunkExecutor, Chunk,
-                                       ChunkRecord, ExecutionTrace,
-                                       WorkStealingScheduler, make_chunks)
-from repro.core.hybrid_executor import (DeviceGroup, HybridExecutor,
-                                        WorkSharedOutput, detect_platform)
-from repro.core.metrics import EWMA, HybridResult, ServeStats, summarize

@@ -168,7 +168,8 @@ class CalibrationCache:
     simulated platforms with different throughput ratios (Hybrid-High
     vs Hybrid-Low) never share entries.
 
-    Backed by a JSON store (section ``unit_times``, keyed per backend)
+    Backed by a JSON store (section ``unit_times``, keyed per lane
+    layout — see ``_backend_name``)
     with the tune cache's merge-on-write / atomic-replace / corrupt-file
     tolerance contract, so a fresh process starts from the previous
     process's measured unit times and plans without probe runs.  Only
@@ -201,12 +202,17 @@ class CalibrationCache:
         return "\t".join((k[0], k[1], f"{k[2]:g}"))
 
     def _backend_name(self) -> str:
+        """The store section: the lane layout's platforms.  Within one
+        layout every group name is one fixed device
+        (``hybrid_executor.detect_platform``): on a TPU host the section
+        is ``tpu+cpu`` and ``host`` is the host CPU, on a CPU-only
+        process it is ``cpu``."""
         if self._backend is None:
-            try:
-                import jax
-                self._backend = jax.default_backend()
-            except Exception:
-                self._backend = "unknown"
+            import jax
+
+            from repro.core.device import platform
+            accel = platform(jax.devices()[0])
+            self._backend = accel if accel == "cpu" else f"{accel}+cpu"
         return self._backend
 
     def _load_disk(self) -> None:

@@ -98,13 +98,31 @@ class HardwareProfile:
 
 
 def tpu_v5e_profile() -> HardwareProfile:
-    """Static fallback: the seed's hard-coded TPU-v5e chip constants
-    (kept for ``calibration.static_time_estimate`` and for
-    ``REPRO_COST_MODEL=0`` runs, where nothing may be measured)."""
+    """The TPU v5e's published chip constants (Google Cloud, "TPU
+    v5e": 197 TFLOP/s bf16, 819 GB/s HBM), for
+    ``calibration.static_time_estimate`` and for ``REPRO_COST_MODEL=0``
+    runs on a v5e, where nothing may be measured."""
     return HardwareProfile(backend="tpu", matmul_flops=197e12,
                            ew_flops=197e12 / 8, mem_bw=819e9,
                            dispatch_s=2e-6, host_bw=5e9, link_bw=50e9,
                            interpret_step_s=0.0, measured=False)
+
+
+def is_v5e(device) -> bool:
+    """JAX reports a v5e chip as ``TPU v5 lite``."""
+    kind = str(getattr(device, "device_kind", "")).lower()
+    return device.platform == "tpu" and ("v5 lite" in kind or "v5e" in kind)
+
+
+def static_profile(device) -> HardwareProfile:
+    """The unmeasured profile for ``device``: only the v5e has one.  Any
+    other device raises rather than being priced as a v5e."""
+    if is_v5e(device):
+        return tpu_v5e_profile()
+    raise ValueError(
+        f"no static hardware profile for {device.platform} device "
+        f"{getattr(device, 'device_kind', '?')!r} (only the TPU v5e has "
+        f"one); unset {ENV_DISABLE} so the profile is measured")
 
 
 # ---------------------------------------------------------------------------
@@ -139,11 +157,8 @@ def _measure_profile(backend: str) -> HardwareProfile:
     xs = jnp.ones((h,), jnp.float32)
     cb = jax.jit(lambda x: jax.pure_callback(
         lambda v: v, jax.ShapeDtypeStruct(x.shape, x.dtype), x))
-    try:
-        t = measure(lambda: cb(xs), warmup=1, iters=3, reduce="min")
-        host_bw = 8.0 * h / max(t, 1e-9)
-    except Exception:                             # backend without callbacks
-        host_bw = tpu_v5e_profile().host_bw
+    t = measure(lambda: cb(xs), warmup=1, iters=3, reduce="min")
+    host_bw = 8.0 * h / max(t, 1e-9)
     return HardwareProfile(backend=backend, matmul_flops=matmul_flops,
                            ew_flops=ew_flops, mem_bw=mem_bw,
                            dispatch_s=max(dispatch_s, 1e-9),
@@ -157,32 +172,29 @@ def _probe_interpret_step(backend: str) -> float:
     size.  On TPU the kernels compile, so the term is zero."""
     if backend == "tpu":
         return 0.0
-    try:
-        import jax
-        import jax.numpy as jnp
-        from jax.experimental import pallas as pl
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-        from repro.core.calibration import measure
+    from repro.core.calibration import measure
 
-        def kern(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1.0
+    def kern(x_ref, o_ref):
+        o_ref[...] = x_ref[...] + 1.0
 
-        x = jnp.zeros((128, 128), jnp.float32)
+    x = jnp.zeros((128, 128), jnp.float32)
 
-        def timed(grid):
-            f = pl.pallas_call(
-                kern,
-                out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32),
-                grid=(grid,),
-                in_specs=[pl.BlockSpec((128, 128), lambda i: (0, 0))],
-                out_specs=pl.BlockSpec((128, 128), lambda i: (0, 0)),
-                interpret=True)
-            g = jax.jit(f)
-            return measure(lambda: g(x), warmup=1, iters=3, reduce="min")
+    def timed(grid):
+        f = pl.pallas_call(
+            kern,
+            out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32),
+            grid=(grid,),
+            in_specs=[pl.BlockSpec((128, 128), lambda i: (0, 0))],
+            out_specs=pl.BlockSpec((128, 128), lambda i: (0, 0)),
+            interpret=True)
+        g = jax.jit(f)
+        return measure(lambda: g(x), warmup=1, iters=3, reduce="min")
 
-        return max((timed(9) - timed(1)) / 8.0, 0.0)
-    except Exception:
-        return 0.0
+    return max((timed(9) - timed(1)) / 8.0, 0.0)
 
 
 _STORE: Optional[JsonStore] = None
@@ -204,14 +216,18 @@ def _store() -> JsonStore:
         return _STORE
 
 
-def get_profile() -> HardwareProfile:
-    """The current backend's profile: memory -> store file -> measured
-    (and persisted).  With the model disabled, the static fallback —
-    never a measurement."""
+def get_profile(device=None) -> HardwareProfile:
+    """The profile of ``device`` (default: the device the call runs on,
+    ``core.device``), one per platform: memory -> store file ->
+    measured on that device (and persisted).  With the model disabled,
+    the static profile — never a measurement, and only for a v5e."""
     import jax
-    backend = jax.default_backend()
+
+    from repro.core.device import current_device
+    dev = current_device(device)
+    backend = dev.platform
     if not enabled():
-        return tpu_v5e_profile()
+        return static_profile(dev)
     store = _store()
     with _LOCK:
         prof = _PROFILES.get(backend)
@@ -228,7 +244,8 @@ def get_profile() -> HardwareProfile:
         else:
             prof = None
     if prof is None:
-        prof = _measure_profile(backend)
+        with jax.default_device(dev):
+            prof = _measure_profile(backend)
         with store.lock:
             store.data().setdefault(_SECTION, {})[backend] = {
                 **asdict(prof), "v": PROFILE_VERSION}
@@ -247,9 +264,9 @@ def reset_profiles() -> None:
         _PROFILES.clear()
 
 
-def predict(terms: CostTerms) -> float:
-    """Convenience: current backend profile's time estimate."""
-    return get_profile().predict(terms)
+def predict(terms: CostTerms, device=None) -> float:
+    """Convenience: the time estimate on ``device``'s profile."""
+    return get_profile(device).predict(terms)
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,7 @@ import threading
 import time
 from contextlib import nullcontext
 from dataclasses import dataclass
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -58,37 +58,50 @@ class DeviceGroup:
     device_class: str                # "accel" | "host"
     slowdown: float = 1.0            # simulated relative slowdown (>=1)
 
+    @property
+    def device(self):
+        """The lane's device (its primary): where its calls run."""
+        return self.devices[0] if self.devices else None
+
 
 def detect_platform(simulated_ratio: float = 4.0,
                     force_simulated: bool = False
                     ) -> Tuple[List[DeviceGroup], bool]:
     """Build device groups.
 
-    Two platforms -> one group per platform (real heterogeneity).  One
-    platform with >=2 devices -> split the devices into two groups
-    (real concurrency, homogeneous hardware).  A single device ->
-    simulate a hybrid pair with the given throughput ratio (Hybrid-Low's
-    GPU:CPU sustained ratio 77.7/20 ~= 3.9 is the default).
+    A TPU -> the paper's hybrid for real: ``accel`` is the TPU chip and
+    ``host`` is the host CPU (``jax.devices("cpu")``).  A process that
+    sees more than one TPU raises: the hybrid drives one chip and its
+    host per process, so a multi-chip host runs one worker process per
+    chip (``ProcWorker(env=serve.transport.chip_env(i))`` behind
+    ``serve.router.Router``) instead of quietly placing every lane on
+    chip 0.  A CPU-only process: >=2 devices (forced host devices) ->
+    split them into two groups (real concurrency, homogeneous
+    hardware); a single device -> simulate a hybrid pair with the given
+    throughput ratio (Hybrid-Low's GPU:CPU sustained ratio 77.7/20 ~=
+    3.9 is the default).
 
     ``force_simulated`` skips detection and always builds the simulated
     pair on the primary device — benchmarks that sweep throughput
     ratios (table2's Hybrid-High vs -Low) need the ratio honored even
     on a multi-device host, where detection would otherwise return a
-    homogeneous real-concurrency pair and silently drop the ratio."""
+    real pair and silently drop the ratio."""
     devs = jax.devices()
     if force_simulated:
         only = devs[:1]
         return ([DeviceGroup("accel", only, "accel", slowdown=1.0),
                  DeviceGroup("host", only, "host",
                              slowdown=simulated_ratio)], True)
-    platforms: Dict[str, List] = {}
-    for d in devs:
-        platforms.setdefault(d.platform, []).append(d)
-    if len(platforms) >= 2:
-        names = sorted(platforms, key=lambda p: -len(platforms[p]))
-        groups = [DeviceGroup("accel", platforms[names[0]], "accel"),
-                  DeviceGroup("host", platforms[names[1]], "host")]
-        return groups, False
+    if devs[0].platform == "tpu":
+        if len(devs) > 1:
+            raise RuntimeError(
+                f"this process sees {len(devs)} TPU chips; the hybrid "
+                "scheduler drives one chip and its host CPU per process. "
+                "Run one worker process per chip (serve.transport."
+                "ProcWorker(env=chip_env(i)) behind serve.router.Router).")
+        return ([DeviceGroup("accel", devs, "accel"),
+                 DeviceGroup("host", jax.devices("cpu")[:1], "host")],
+                False)
     if len(devs) >= 2:
         half = max(len(devs) // 2, 1)
         return ([DeviceGroup("accel", devs[:half], "accel"),
@@ -213,7 +226,8 @@ class HybridExecutor:
                 if uc is not None:
                     from repro.core import cost_model
                     if cost_model.enabled():
-                        t_unit = cost_model.predict(uc) * g.slowdown
+                        t_unit = cost_model.predict(uc, g.device) \
+                            * g.slowdown
                         self.tracker.seed(g.name, t_unit)
                         continue
                 if not probe:

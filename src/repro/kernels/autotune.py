@@ -49,9 +49,11 @@ import math
 import json
 import os
 import re
+import sys
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.core.device import platform
 from repro.core.persist import JsonStore
 
 Config = Dict[str, Any]
@@ -187,9 +189,9 @@ def default_config(seed: Config, safe: Config) -> Config:
     the hand-written Pallas kernel with its seed tiles on TPU —
     disabling *search* must not silently swap the platform
     implementation — and the XLA formulation elsewhere (interpret-mode
-    Pallas is never a sane default off-TPU)."""
-    import jax
-    return dict(seed) if jax.default_backend() == "tpu" else dict(safe)
+    Pallas is never a sane default off-TPU).  The TPU is the platform
+    of the device the call runs on (``core.device``)."""
+    return dict(seed) if platform() == "tpu" else dict(safe)
 
 
 def search_enabled() -> bool:
@@ -296,11 +298,19 @@ def _make_predict(cost_fn: Optional[CostFn]):
     from repro.core import cost_model
     if not cost_model.enabled():
         return None
-    try:
-        profile = cost_model.get_profile()
-    except Exception:
-        return None
+    profile = cost_model.get_profile()
     return lambda cfg: profile.predict(cost_fn(cfg))
+
+
+def _report_refusal(kernel: str, cfg: Config, exc: BaseException) -> None:
+    """A candidate the backend refused (a tiling its compiler rejects)
+    is skipped, but never silently: kernel, config and the error's
+    first line go to stderr."""
+    lines = str(exc).strip().splitlines()
+    first = lines[0] if lines else ""
+    print(f"autotune: {kernel} on {platform()} refused "
+          f"{json.dumps(cfg, sort_keys=True, default=str)}: "
+          f"{type(exc).__name__}: {first}", file=sys.stderr, flush=True)
 
 
 def autotune(kernel: str, shape_bucket: str, candidates: Sequence[Config],
@@ -318,8 +328,8 @@ def autotune(kernel: str, shape_bucket: str, candidates: Sequence[Config],
     candidates (merged over ``default``) are built with ``make_fn``
     and timed — all of them, or only the model's top-K when a
     ``cost_fn`` is supplied (see ``_select_top_k``).  Failing
-    candidates (e.g. a tiling the backend rejects) are skipped.  The
-    winner persists to the tune cache."""
+    candidates (e.g. a tiling the backend rejects) are skipped and
+    reported on stderr.  The winner persists to the tune cache."""
     default = dict(default)
     pin = pinned_config(kernel)
     if pin is not None:
@@ -327,8 +337,7 @@ def autotune(kernel: str, shape_bucket: str, candidates: Sequence[Config],
     if not search_enabled():
         return default
 
-    import jax
-    backend = jax.default_backend()
+    backend = platform()
     cache = get_tune_cache()
     hit = cache.get(backend, kernel, shape_bucket)
     if hit is not None and isinstance(hit.get("config"), dict):
@@ -365,8 +374,8 @@ def autotune(kernel: str, shape_bucket: str, candidates: Sequence[Config],
                     cache.put(backend, kernel, shape_bucket, t_cfg,
                               t * 1e6, via=f"transfer:{near_bkt}")
                     return t_cfg
-                except Exception:
-                    pass                    # bad seed: fall back to search
+                except Exception as e:      # bad seed: fall back to search
+                    _report_refusal(kernel, t_cfg, e)
 
     k = top_k()
     if predict is not None and k > 0 and len(merged) > k:
@@ -377,7 +386,8 @@ def autotune(kernel: str, shape_bucket: str, candidates: Sequence[Config],
     for cfg in merged:
         try:
             t = tmr(make_fn(cfg))
-        except Exception:
+        except Exception as e:
+            _report_refusal(kernel, cfg, e)
             continue
         if t < best_t:
             best_t, best_cfg = t, cfg
@@ -403,8 +413,7 @@ def cached_or_default(kernel: str, shape_bucket: str, default: Config
         return {**default, **pin}
     if not search_enabled():
         return default
-    import jax
-    hit = get_tune_cache().get(jax.default_backend(), kernel, shape_bucket)
+    hit = get_tune_cache().get(platform(), kernel, shape_bucket)
     if hit is not None and isinstance(hit.get("config"), dict):
         return {**default, **hit["config"]}
     return default
@@ -413,5 +422,4 @@ def cached_or_default(kernel: str, shape_bucket: str, default: Config
 def tuned_entry(kernel: str, shape_bucket: str) -> Optional[dict]:
     """Cache entry (config + measured us) if present — benchmark
     reporting helper; never triggers a search."""
-    import jax
-    return get_tune_cache().get(jax.default_backend(), kernel, shape_bucket)
+    return get_tune_cache().get(platform(), kernel, shape_bucket)

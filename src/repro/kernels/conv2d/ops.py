@@ -15,7 +15,9 @@ from repro.core.cost_model import CostTerms
 from repro.kernels.autotune import (Config, autotune, bucket,
                                     cached_or_default, default_config,
                                     freeze, is_tracer)
-from repro.kernels.conv2d.conv2d import conv2d_pallas, conv2d_shift_add
+from repro.core.device import platform
+from repro.kernels.conv2d.conv2d import (conv2d_pallas, conv2d_shift_add,
+                                         window_shape)
 from repro.kernels.conv2d.ref import conv2d_ref
 
 # Seed constants (PR 1): 1-D row tiling, whole image resident.
@@ -24,14 +26,30 @@ SEED_CONFIG: Config = {"impl": "pallas", "row_tile": 64, "col_tile": 0}
 DEFAULT_CONFIG: Config = {"impl": "xla_conv", "row_tile": 64, "col_tile": 0}
 
 
+# TPU v5e: a halo window over 1 MiB makes the kernel's shifted
+# temporaries overflow the 16 MiB of scoped VMEM (the compiler refuses
+# 512x512 and 512xfull-width tiles of a 768^2, K=15 image).
+TPU_WINDOW_BYTES = 1 << 20
+
+
 def candidates(H: int, W: int, K: int):
-    """Per-shape config space: XLA variants + 2-D Pallas tilings."""
-    cands = [{"impl": "xla_conv"}, {"impl": "xla_shift"}]
+    """Per-shape config space: XLA variants + 2-D Pallas tilings.
+
+    On the TPU, XLA's own convolution is left out: the TPU compiler
+    did not finish a single-channel 15x15 convolution of a 768^2 image
+    in 15 minutes.  Pallas tilings whose halo window exceeds
+    ``TPU_WINDOW_BYTES`` are left out there too."""
+    tpu = platform() == "tpu"
+    cands = [] if tpu else [{"impl": "xla_conv"}]
+    cands.append({"impl": "xla_shift"})
     for rt in (64, 128, 256, 512):
         if rt > max(H, 64) * 2:
             continue
         for ct in (0, 128, 256, 512):
             if ct and ct > max(W, 128) * 2:
+                continue
+            win = window_shape(H, W, K, rt, ct)
+            if tpu and 4 * win[0] * win[1] > TPU_WINDOW_BYTES:
                 continue
             cands.append({"impl": "pallas", "row_tile": rt, "col_tile": ct})
     return cands

@@ -10,8 +10,8 @@ import jax.numpy as jnp
 from repro.core.cost_model import CostTerms
 from repro.kernels.autotune import (Config, autotune, bucket,
                                     cached_or_default, default_config,
-                                    freeze, get_tune_cache, is_tracer,
-                                    pinned_config, search_enabled)
+                                    freeze, is_tracer, pinned_config,
+                                    search_enabled, tuned_entry)
 from repro.kernels.flash_attention.flash_attention import (
     attention_blocked_xla, flash_attention_pallas)
 from repro.kernels.flash_attention.ref import attention_ref
@@ -164,10 +164,8 @@ def model_config(q, k, v, *, causal: bool = True) -> Optional[Config]:
         return None
     B, T, H, d = q.shape
     S = k.shape[1]
-    import jax
-    hit = get_tune_cache().get(
-        jax.default_backend(), "flash_attention",
-        shape_bucket(B * H, T, S, d, causal))
+    hit = tuned_entry("flash_attention",
+                      shape_bucket(B * H, T, S, d, causal))
     if hit is None or not isinstance(hit.get("config"), dict):
         return None
     return _differentiable({**default, **hit["config"]}, causal)

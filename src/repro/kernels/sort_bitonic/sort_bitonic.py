@@ -22,20 +22,21 @@ from repro.kernels.common import resolve_interpret
 def _bitonic_rows(x: jnp.ndarray) -> jnp.ndarray:
     """Sort each row ascending; L = power of two (static unrolled net).
 
-    The stride-j partner of lane i is i^j, i.e. the matching lane in the
-    other j-wide half of each 2j block — so partner values come from a
-    reshape + flip of the block axis, never a gather (an unrolled
-    ``jnp.take`` network compiles catastrophically: each sweep is an
-    L-wide dynamic gather, and interpret mode lowers log^2(L) of them)."""
-    TR, L = x.shape
+    The stride-j partner of lane i is i^j, i.e. lane i+j for the low
+    half of each 2j block and lane i-j for the high half — so partner
+    values come from two lane rotations and a select, never a gather
+    (an unrolled ``jnp.take`` network compiles catastrophically: each
+    sweep is an L-wide dynamic gather) and never a reversal (which the
+    TPU kernel compiler does not lower)."""
+    _, L = x.shape
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, L), dimension=1)
     k = 2
     while k <= L:
         j = k // 2
         while j >= 1:
-            xr = x.reshape(TR, L // (2 * j), 2, j)
-            px = jnp.flip(xr, axis=2).reshape(TR, L)
             is_lo = (lane & j) == 0          # lane < partner
+            px = jnp.where(is_lo, jnp.roll(x, -j, axis=1),
+                           jnp.roll(x, j, axis=1))
             ascending = (lane & k) == 0
             keep_min = is_lo == ascending
             x = jnp.where(keep_min, jnp.minimum(x, px), jnp.maximum(x, px))

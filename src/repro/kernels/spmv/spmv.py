@@ -4,16 +4,15 @@ The paper sorts rows by nnz and sends dense rows to the GPU and the
 sparse tail to the CPU.  The TPU version keeps the same transform:
 
   * rows are sorted by nnz and split at a threshold K;
-  * the dense bin is ELL-packed — (R, K) values + column indices — and
-    this kernel streams row tiles through VMEM, forming y via a
-    gather + row-sum (VPU) per tile;
+  * the dense bin is ELL-packed — (R, K) values + column indices; XLA
+    gathers x at those columns and this kernel streams row tiles of
+    values and gathered x through VMEM, forming y as a row-sum (VPU)
+    per tile;
   * the sparse tail (rows with nnz > K would explode ELL padding; rows
     with tiny nnz waste it) is handled by a COO segment-sum on the
     "host path" (ops.py) — exactly the paper's CPU-side share.
 
-VMEM: tile (TR, K) f32 values + i32 idx + x (C,) resident.
-TR=256, K<=64, C<=128k -> ~0.7 MiB + x.  Documented limit: x must fit
-VMEM (shard columns above that).
+VMEM: two (TR, K) f32 tiles (values, gathered x).
 """
 from __future__ import annotations
 
@@ -25,12 +24,10 @@ from jax.experimental import pallas as pl
 from repro.kernels.common import resolve_interpret
 
 
-def _spmv_kernel(vals_ref, idx_ref, x_ref, o_ref):
-    vals = vals_ref[...]                       # (TR, K)
-    idx = idx_ref[...]                         # (TR, K) int32
-    x = x_ref[...]                             # (C,)
-    gathered = jnp.take(x, idx, axis=0)        # (TR, K)
-    o_ref[...] = jnp.sum(vals * gathered, axis=1)
+def _spmv_kernel(vals_ref, xg_ref, o_ref):
+    # vals, xg: (TR, K) — xg holds x gathered at each entry's column
+    o_ref[...] = jnp.sum(vals_ref[...] * xg_ref[...], axis=1,
+                         keepdims=True)
 
 
 def spmv_ell_pallas(vals: jnp.ndarray, idx: jnp.ndarray, x: jnp.ndarray,
@@ -47,16 +44,19 @@ def spmv_ell_pallas(vals: jnp.ndarray, idx: jnp.ndarray, x: jnp.ndarray,
         vals = jnp.pad(vals, ((0, pad), (0, 0)))
         idx = jnp.pad(idx, ((0, pad), (0, 0)))
     grid = (vals.shape[0] // row_tile,)
+    # the column gather runs in XLA: the TPU kernel compiler gathers
+    # only within one vreg, and x spans many
+    xg = jnp.take(x, idx.astype(jnp.int32), axis=0)
     y = pl.pallas_call(
         _spmv_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((row_tile, K), lambda i: (i, 0)),
             pl.BlockSpec((row_tile, K), lambda i: (i, 0)),
-            pl.BlockSpec(x.shape, lambda i: (0,)),
         ],
-        out_specs=pl.BlockSpec((row_tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((vals.shape[0],), vals.dtype),
+        # (rows, 1): the row sums come out along sublanes
+        out_specs=pl.BlockSpec((row_tile, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((vals.shape[0], 1), vals.dtype),
         interpret=interpret,
-    )(vals, idx.astype(jnp.int32), x)
-    return y[:R]
+    )(vals, xg)
+    return y[:R, 0]

@@ -182,6 +182,8 @@ def main(argv=None):
                     help="--stream: dump the final ServeStats snapshot "
                          "+ placement audit as JSON")
     args = ap.parse_args(argv)
+    from repro.core import compile_cache
+    compile_cache.enable()
 
     cfg = registry.get(args.arch)
     if not args.full:

@@ -32,6 +32,8 @@ def main(argv=None):
     ap.add_argument("--no-steal", action="store_true",
                     help="disable intra-step work stealing")
     args = ap.parse_args(argv)
+    from repro.core import compile_cache
+    compile_cache.enable()
 
     cfg = registry.get(args.arch)
     if not args.full:

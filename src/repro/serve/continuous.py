@@ -456,6 +456,8 @@ class LMStepper:
         self._jax, self._jnp = jax, jnp
         self.cfg = cfg
         self.params = params
+        self._params_on: Dict[object, object] = {}
+        self._params_lock = threading.Lock()
         self.prompt_len = int(prompt_len)
         self.new_tokens = int(new_tokens)
         self.cache_len = int(cache_len or (prompt_len + new_tokens + 1))
@@ -480,11 +482,26 @@ class LMStepper:
 
         self._prefill = _prefill
 
+    def _lane_params(self):
+        """``params`` on the device this call runs on, copied there once
+        per device: prefill and decode may run on different lanes (the
+        TPU and the host CPU), and params left on one device would
+        either pull the other lane's calls onto it or cross the host
+        link at every step."""
+        from repro.core.device import current_device
+        dev = current_device()
+        with self._params_lock:
+            p = self._params_on.get(dev)
+            if p is None:
+                p = self._params_on[dev] = self._jax.device_put(
+                    self.params, dev)
+        return p
+
     # -- protocol ----------------------------------------------------------
     def init_slots(self):
         jnp = self._jnp
         zeros = jnp.zeros((self.n_slots, self.prompt_len), jnp.int32)
-        _, caches = self._prefill(self.params, zeros)
+        _, caches = self._prefill(self._lane_params(), zeros)
         return {"caches": caches,
                 "tokens": jnp.zeros((self.n_slots,), jnp.int32),
                 "pos": jnp.zeros((self.n_slots,), jnp.int32)}
@@ -492,7 +509,7 @@ class LMStepper:
     def prefill(self, spec):
         jax = self._jax
         prompt = self._jnp.asarray(spec.arrays[0])
-        first, caches = self._prefill(self.params, prompt)
+        first, caches = self._prefill(self._lane_params(), prompt)
         first_host = [int(t) for t in jax.device_get(first)]
         rows = []
         for b in range(prompt.shape[0]):
@@ -503,7 +520,9 @@ class LMStepper:
 
     def insert(self, state, slot, row_state):
         jax, jnp = self._jax, self._jnp
-        row_cache, first = row_state
+        from repro.core.device import current_device
+        # the row was prefilled on the prefill lane's device
+        row_cache, first = jax.device_put(row_state, current_device())
         caches = self._cache_update(state["caches"], row_cache, slot)
         return {"caches": caches,
                 "tokens": state["tokens"].at[slot].set(
@@ -511,7 +530,7 @@ class LMStepper:
                 "pos": state["pos"].at[slot].set(self.prompt_len)}
 
     def step(self, state):
-        toks, caches = self._slot_step(self.params, state["tokens"],
+        toks, caches = self._slot_step(self._lane_params(), state["tokens"],
                                        state["caches"], state["pos"])
         new = {"caches": caches, "tokens": toks,
                "pos": state["pos"] + 1}
@@ -534,7 +553,7 @@ class LMStepper:
         state = self.init_slots()
         for b in batch_sizes:
             first, caches = self._prefill(
-                self.params,
+                self._lane_params(),
                 jnp.zeros((int(b), self.prompt_len), jnp.int32))
             row = self._slice_cache(caches, 0)
             state = self.insert(state, 0, (row, first[0]))

@@ -847,6 +847,9 @@ class Scheduler:
             # the measured service time against this
             self.audit.record(r.req_id, r.workload, decision.kind,
                               decision.est_exec_s, decision.alternatives)
+            # where it runs, for the caller (a retry overwrites it)
+            r.future.meta["placement"] = {"kind": decision.kind,
+                                          "groups": list(decision.groups)}
         ex = _Execution([r for r in kept], [r.payload for r in kept],
                         decision, t_dispatch=now,
                         est_span=decision.est_exec_s)
@@ -1004,16 +1007,21 @@ class Scheduler:
         probes: a fresh process must place with last_probe_runs == 0).
         Prefill is compute-bound, decode bandwidth-bound — predict()
         rates them against the measured backend profile, scaled by
-        each group's slowdown.  None when no lane is alive (caller
+        each group's slowdown, on the profile of that group's device.
+        None when no lane is alive (caller
         delivers a structured rejection)."""
         from repro.core import cost_model
         with self._lock:
             loads = [GroupLoad(ld.name, None, ld.busy_until, ld.alive)
                      for ld in self._loads.values()]
-        pre = {g.name: cost_model.predict(stepper.prefill_cost) * g.slowdown
-               for g in self.groups}
-        dec = {g.name: cost_model.predict(stepper.decode_cost) * g.slowdown
-               for g in self.groups}
+        def prior(cost, g):
+            # with the model off, lanes rank by their slowdown alone
+            if not cost_model.enabled():
+                return g.slowdown
+            return cost_model.predict(cost, g.device) * g.slowdown
+
+        pre = {g.name: prior(stepper.prefill_cost, g) for g in self.groups}
+        dec = {g.name: prior(stepper.decode_cost, g) for g in self.groups}
         return plan_disaggregation(loads, pre, dec)
 
     def _engine_reject(self, req: Request, exc: BaseException) -> None:
@@ -1048,7 +1056,7 @@ class Scheduler:
         if uc is not None:
             from repro.core import cost_model
             if cost_model.enabled():
-                return cost_model.predict(uc) * g.slowdown
+                return cost_model.predict(uc, g.device) * g.slowdown
         return None
 
     # -- lane workers ---------------------------------------------------

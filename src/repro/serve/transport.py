@@ -53,6 +53,27 @@ from repro.serve.request_queue import Rejection, RequestRejected
 _LEN = struct.Struct(">I")
 
 
+def chip_env(chip: Optional[int]) -> Dict[str, str]:
+    """Environment for a worker process that owns exactly one TPU chip
+    (``chip``), through libtpu's per-process chip visibility; a
+    process limited to a subset of the host's chips does not take the
+    host-wide libtpu lock.  ``chip=None``: the worker runs on the CPU
+    (the extra workers of a fleet larger than the host's chip count).
+    One chip belongs to one process: never give two workers the same
+    ``chip``."""
+    if chip is None:
+        return {"JAX_PLATFORMS": "cpu"}
+    import socket
+    with socket.socket() as s:                 # a free slice-builder port
+        s.bind(("localhost", 0))
+        port = s.getsockname()[1]
+    return {"TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_VISIBLE_CHIPS": str(int(chip)),
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
+
+
 def _env_float(name: str, default: float) -> float:
     try:
         return float(os.environ.get(name, default))
@@ -296,15 +317,21 @@ class ProcWorker:
     The child runs ``python -m repro.serve.transport --worker`` with a
     dedicated pipe pair passed by fd; ``env`` overrides (on top of the
     parent's environment) point it at the shared calibration/tune
-    stores and any forced-device ``XLA_FLAGS``.  ``kill()`` is a real
+    stores and any forced-device ``XLA_FLAGS``; on a TPU host each
+    worker's ``env`` includes ``chip_env(i)`` so it owns one chip.
+    ``lm`` names an arch whose reduced config the child serves as
+    ``serve-lm-cb/<arch>`` (seed-0 weights, prompt 16, 8 new tokens),
+    next to the Table-1 registry.  ``kill()`` is a real
     SIGKILL; ``stall()``/``resume()`` are SIGSTOP/SIGCONT; ``restart``
     spawns a fresh child under the same name (the cold rejoin path —
     its first placements come off the shared store)."""
 
     def __init__(self, name: str, env: Optional[Dict[str, str]] = None,
-                 hb_interval_s: Optional[float] = None):
+                 hb_interval_s: Optional[float] = None,
+                 lm: Optional[str] = None):
         self.name = name
         self.env = dict(env or {})
+        self.lm = lm
         self.hb_interval_s = (hb_interval_s if hb_interval_s is not None
                               else _env_float("REPRO_FLEET_HB_S", 1.0))
         self._proc: Optional[subprocess.Popen] = None
@@ -333,6 +360,8 @@ class ProcWorker:
         cmd = [sys.executable, "-m", "repro.serve.transport", "--worker",
                "--name", self.name, "--in-fd", str(r_child),
                "--out-fd", str(w_child), "--hb", str(self.hb_interval_s)]
+        if self.lm:
+            cmd += ["--lm", self.lm]
         # stdout -> devnull: the frame protocol owns its own fds, and
         # jax/adapter prints must go somewhere harmless; stderr inherits
         # so a crashing child leaves a traceback in the parent's log
@@ -443,6 +472,23 @@ class ProcWorker:
 # ---------------------------------------------------------------------------
 # child entry point
 # ---------------------------------------------------------------------------
+def _register_lm(arch: str) -> str:
+    """Serve ``arch`` (reduced config, seed-0 weights) through the
+    continuous-batching adapter: every worker given the same ``arch``
+    holds the same model, so any worker answers an LM request alike."""
+    import jax
+
+    from repro.configs import registry
+    from repro.models import model_zoo, param
+    from repro.workloads import requests as adapters
+
+    cfg = registry.get(arch).reduced()
+    params = jax.jit(lambda k: param.values(model_zoo.init(cfg, k)))(
+        jax.random.key(0))
+    return adapters.make_continuous_lm_adapter(
+        cfg, params, prompt_len=16, new_tokens=8, warm_background=False)
+
+
 def worker_main(argv=None) -> int:
     """Host one Scheduler behind a pipe transport (see module doc)."""
     ap = argparse.ArgumentParser()
@@ -451,16 +497,21 @@ def worker_main(argv=None) -> int:
     ap.add_argument("--in-fd", type=int, required=True)
     ap.add_argument("--out-fd", type=int, required=True)
     ap.add_argument("--hb", type=float, default=1.0)
+    ap.add_argument("--lm", default=None)
     args = ap.parse_args(argv)
 
     rfile = os.fdopen(args.in_fd, "rb")
     wfile = os.fdopen(args.out_fd, "wb", buffering=0)
     wlock = threading.Lock()
 
+    from repro.core import compile_cache
+    compile_cache.enable()
     from repro.core.calibration import get_calibration_cache
     from repro.obs import get_recorder
     from repro.serve.scheduler import Scheduler
 
+    if args.lm:
+        _register_lm(args.lm)
     sched = Scheduler()
     rec = get_recorder()
     stop = threading.Event()

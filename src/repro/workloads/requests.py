@@ -192,15 +192,17 @@ def _conv_spec(payload: Optional[dict]) -> RequestSpec:
                                   int(p.get("seed", 0)))
     H, W = img.shape
     K = w.shape[0]
-    cfg = tuned_config(img, w)
 
+    # the tuned config is resolved where the request runs: each lane
+    # tunes for its own device (a search at most once per platform and
+    # shape bucket, then a cache lookup)
     def run_one():
-        out = conv2d(img, w, config=cfg)
+        out = conv2d(img, w, config=tuned_config(img, w))
         out.block_until_ready()
         return out
 
     def run_share(group, start, n):
-        out = conv.conv_rows(img, w, start, n, config=cfg)
+        out = conv.conv_rows(img, w, start, n, config=tuned_config(img, w))
         out.block_until_ready()
         return out
 
@@ -272,10 +274,11 @@ def _hist_spec(payload: Optional[dict]) -> RequestSpec:
     n = x.shape[0]
     unit = max(n // 64, 1)
     units = max(n // unit, 1)
-    cfg = tuned_config(x[:max(n // 2, 1)], n_bins)
+    half = x[:max(n // 2, 1)]
 
+    # tuned where the request runs: each lane's own device decides
     def run_one():
-        out = histogram(x, n_bins, config=cfg)
+        out = histogram(x, n_bins, config=tuned_config(half, n_bins))
         out.block_until_ready()
         return out
 
@@ -283,7 +286,7 @@ def _hist_spec(payload: Optional[dict]) -> RequestSpec:
         if k <= 0:
             return jnp.zeros((n_bins,), jnp.int32)
         out = histogram(x[start * unit:(start + k) * unit], n_bins,
-                        config=cfg)
+                        config=tuned_config(half, n_bins))
         out.block_until_ready()
         return out
 
@@ -1048,17 +1051,18 @@ def _bilateral_spec(payload: Optional[dict]) -> RequestSpec:
                                       seed)
     H, W = img.shape
     K = 2 * radius + 1
-    cfg = tuned_config(img, sp, rl)
 
+    # tuned where the request runs: each lane's own device decides
     def run_one():
-        out = bilateral_filter(img, sp, rl, config=cfg)
+        out = bilateral_filter(img, sp, rl, config=tuned_config(img, sp, rl))
         out.block_until_ready()
         return out
 
     def run_share(group, start, n):
         lo = max(0, start - radius)
         hi = min(H, start + n + radius)
-        out = bilateral_filter(img[lo:hi], sp, rl, config=cfg)
+        out = bilateral_filter(img[lo:hi], sp, rl,
+                               config=tuned_config(img, sp, rl))
         out = out[start - lo:start - lo + n]
         out.block_until_ready()
         return out

@@ -7,6 +7,7 @@ measure(warmup=0) cold-timing path, and zero-probe fresh-process
 planning."""
 import json
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -61,10 +62,18 @@ def test_profile_measured_and_persisted(stores):
 
 
 def test_profile_static_fallback_when_disabled(stores, monkeypatch):
+    """With the model off, only a v5e gets the static profile; any
+    other device raises instead of being priced as a v5e."""
     monkeypatch.setenv("REPRO_COST_MODEL", "0")
-    p = cost_model.get_profile()
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    p = cost_model.get_profile(v5e)
     assert not p.measured
-    assert p.matmul_flops == 197e12          # the seed's v5e constant
+    assert p.matmul_flops == 197e12          # the v5e's published peak
+    with pytest.raises(ValueError, match="no static hardware profile"):
+        cost_model.get_profile()             # this process's CPU
+    v4 = SimpleNamespace(platform="tpu", device_kind="TPU v4")
+    with pytest.raises(ValueError, match="TPU v4"):
+        cost_model.get_profile(v4)
 
 
 def test_predict_properties():

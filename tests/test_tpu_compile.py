@@ -1,0 +1,117 @@
+"""The main-path Pallas kernels compile for the TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what the chip's
+compiler refuses: slices not aligned to the (8, 128) tiling, more VMEM
+than a kernel may use, gathers the TPU cannot lower.  These tests
+compile each kernel with ``interpret=False`` for a described ``v5e:2x2``
+topology at the widths ``chip_smoke.py`` runs.  Nothing runs, so they
+need no chip.
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and under pytest-xdist only
+the worker given this file should.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.bilateral.bilateral import bilateral_pallas
+from repro.kernels.conv2d.conv2d import conv2d_pallas
+from repro.kernels.flash_attention.flash_attention import \
+    flash_attention_pallas
+from repro.kernels.gmm.gmm import gmm_pallas
+from repro.kernels.hist.hist import hist_pallas
+from repro.kernels.sort_bitonic.sort_bitonic import sort_rows_pallas
+from repro.kernels.spmv.spmv import spmv_ell_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One chip of a described v5e:2x2; the persistent compile cache is
+    off meanwhile (a described chip's executables cannot be read back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                    # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+CASES = {
+    "conv2d": (lambda a, w: conv2d_pallas(a, w, row_tile=64,
+                                          interpret=False),
+               [(768, 768), (15, 15)]),
+    "conv2d_2d_tiles": (lambda a, w: conv2d_pallas(
+        a, w, row_tile=128, col_tile=128, interpret=False),
+        [(768, 768), (15, 15)]),
+    # a §5.4.3 row share of the smoke's conv: 384 rows + halo, under
+    # a tiling tuned for the whole image
+    "conv2d_row_share": (lambda a, w: conv2d_pallas(
+        a, w, row_tile=512, col_tile=256, interpret=False),
+        [(391, 768), (15, 15)]),
+    "hist": (lambda x: hist_pallas(x, 256, tile=2048, interpret=False),
+             [((1 << 21,), jnp.int32)]),
+    "spmv": (lambda v, i, x: spmv_ell_pallas(v, i, x, row_tile=256,
+                                             interpret=False),
+             [(4096, 32), ((4096, 32), jnp.int32), (4096,)]),
+    "sort_bitonic": (lambda x: sort_rows_pallas(x, row_tile=256,
+                                                interpret=False),
+                     [(256, 256)]),
+    "bilateral": (lambda a, s, r: bilateral_pallas(a, s, r, row_tile=64,
+                                                   interpret=False),
+                  [(256, 256), (15, 15), (256,)]),
+    "flash_attention": (lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, block_q=256, block_k=256, interpret=False),
+        [(16, 512, 64)] * 3),
+    "gmm": (lambda x, w: gmm_pallas(x, w, interpret=False),
+            [(8, 256, 512), (8, 512, 512)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, shapes = CASES[name]
+    args = [_spec(one_chip, *s) if isinstance(s[0], tuple)
+            else _spec(one_chip, s) for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    # the Pallas kernel itself reached the chip's compiler
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_conv_tpu_candidates_compile(one_chip, monkeypatch):
+    """Every conv config the autotuner may try on the TPU compiles at
+    the smoke's width: XLA's own convolution is left out there, and so
+    are tilings whose halo window overflows VMEM."""
+    from repro.kernels.conv2d import ops
+    from repro.kernels.conv2d.conv2d import conv2d_shift_add
+
+    monkeypatch.setattr(ops, "platform", lambda: "tpu")
+    cands = ops.candidates(768, 768, 15)
+    assert {"impl": "xla_conv"} not in cands
+    assert {"impl": "pallas", "row_tile": 512, "col_tile": 512} not in cands
+    args = [_spec(one_chip, (768, 768)), _spec(one_chip, (15, 15))]
+    for cfg in cands:
+        if cfg["impl"] == "xla_shift":
+            fn = conv2d_shift_add
+        else:
+            fn = functools.partial(conv2d_pallas, row_tile=cfg["row_tile"],
+                                   col_tile=cfg["col_tile"],
+                                   interpret=False)
+        jax.jit(fn).lower(*args).compile()

@@ -97,18 +97,6 @@ def run():
                .block_until_ready())
     _row("conv_512", tuned, seed, cfg, "15x15")
 
-    # ----------------------------------------------------------- spmv
-    from repro.kernels.spmv import ops as spmv_ops
-    from repro.kernels.spmv.ref import spmv_ell_ref
-    vals = jax.random.normal(jax.random.key(7), (4096, 32))
-    idx = jax.random.randint(jax.random.key(8), (4096, 32), 0, 4096)
-    xv = jax.random.normal(jax.random.key(9), (4096,))
-    cfg = spmv_ops.tuned_config(vals, idx, xv)
-    seed = _t(lambda: spmv_ell_ref(vals, idx, xv).block_until_ready())
-    tuned = _t(lambda: spmv_ops.spmv_ell(vals, idx, xv, config=cfg)
-               .block_until_ready())
-    _row("spmv_4k", tuned, seed, cfg, "ELL_K32")
-
     # ----------------------------------------------------------- sort
     from repro.kernels.sort_bitonic import ops as sort_ops
     from repro.kernels.sort_bitonic.ref import sort_rows_ref

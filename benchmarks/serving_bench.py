@@ -89,7 +89,11 @@ FULL13_VERSION = "f2"
 # Chaos availability scenario (mid-trace lane death + revive).
 CHAOS_VERSION = "c1"
 # Fleet scenario (router over K worker processes, kill-one-of-K).
-FLEET_VERSION = "ft1"
+# ft2: the arrival rate is 0.9 / (mean service time of one request
+# through the warm K-worker fleet, pipe transport included); ft1 took
+# the service time of one in-process lane, which would put this
+# process on JAX before the workers open their chips.
+FLEET_VERSION = "ft2"
 # Observability rows (tracing overhead gate + informational audit).
 OBS_VERSION = "o1"
 
@@ -844,10 +848,10 @@ def run_fleet(smoke: bool, mix=None, trace_path=None):
         rb = _fleet_router(k, store)
         _broadcast_warm(rb, mix)
         if trace is None:
-            # 0.9x ONE lane's rate against a K-worker fleet: each
-            # survivor can absorb the dead worker's range without
-            # saturating — goodput through the outage is the row, not
-            # raw capacity
+            # 0.9x the rate one worker serves one request at a time
+            # (FLEET_VERSION ft2), against K workers: each survivor can
+            # absorb the dead worker's range without saturating —
+            # goodput through the outage is the row, not raw capacity
             rate = 0.9 / max(_fleet_service_time(rb, mix), 1e-6)
             trace = make_trace(rate, n, mix, seed=29)
             span = trace[-1][0]

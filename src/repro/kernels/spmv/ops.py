@@ -15,71 +15,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.cost_model import CostTerms
-from repro.kernels.autotune import (Config, autotune, bucket,
-                                    cached_or_default, default_config,
-                                    freeze, is_tracer)
+from repro.kernels.autotune import Config, freeze
 from repro.kernels.spmv.spmv import spmv_ell_pallas
 from repro.kernels.spmv.ref import spmv_coo_ref, spmv_ell_ref
 
-# Seed constants (PR 1) / safe default when search is disabled.
-SEED_CONFIG: Config = {"impl": "pallas", "row_tile": 256}
-DEFAULT_CONFIG: Config = {"impl": "xla_ell", "row_tile": 256}
-
-
-def candidates(R: int, K: int):
-    cands = [{"impl": "xla_ell"}]
-    for rt in (128, 256, 512):
-        if rt > max(R, 128) * 2:
-            continue
-        cands.append({"impl": "pallas", "row_tile": rt})
-    return cands
-
-
-def shape_bucket(R: int, K: int) -> str:
-    return f"R{bucket(R)}_K{bucket(K)}"
+# The path every call without a config takes.  The Pallas kernel sums rows of x gathered by
+# XLA (the TPU kernel compiler gathers only within one vreg), so it
+# computes what ``xla_ell`` computes and writes and reads back an extra
+# (R, K) array: it runs only when a config names it, until x can be
+# gathered inside VMEM.
+DEFAULT_CONFIG: Config = {"impl": "xla_ell"}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _ell_cfg(vals, idx, x, cfg):
     c = dict(cfg)
-    if c.get("impl", "pallas") == "xla_ell":
+    if c.get("impl", "xla_ell") == "xla_ell":
         return spmv_ell_ref(vals, idx, x)
     return spmv_ell_pallas(vals, idx, x,
                            row_tile=int(c.get("row_tile", 256)))
 
 
-def cost_terms(cfg: Config, R: int, K: int) -> CostTerms:
-    """Analytic work of one candidate (ranks the autotune search)."""
-    if cfg.get("impl", "pallas") == "xla_ell":
-        return CostTerms(flops=2.0 * R * K, bytes=4.0 * (3 * R * K + 2 * R))
-    rt = max(int(cfg.get("row_tile", 256)), 1)
-    Rp = -(-R // rt) * rt                           # padded rows
-    from repro.kernels.common import default_interpret
-    return CostTerms(flops=2.0 * Rp * K, bytes=4.0 * (3 * Rp * K + 2 * Rp),
-                     steps=Rp // rt,
-                     interpret_steps=(Rp // rt if default_interpret()
-                                      else 0))
-
-
-def tuned_config(vals, idx, x) -> Config:
-    R, K = vals.shape
-    default = default_config(SEED_CONFIG, DEFAULT_CONFIG)
-    if is_tracer(vals) or is_tracer(x):
-        return cached_or_default("spmv", shape_bucket(R, K), default)
-    return autotune(
-        "spmv", shape_bucket(R, K), candidates(R, K),
-        lambda cfg: lambda: _ell_cfg(vals, idx, x, freeze(cfg)),
-        default,
-        cost_fn=lambda cfg: cost_terms(cfg, R, K))
-
-
 def spmv_ell(vals, idx, x, *, config: Optional[Config] = None):
-    """ELL spmv with an autotuned implementation (config=None ->
-    per-backend tuned)."""
-    if config is None:
-        config = tuned_config(vals, idx, x)
-    return _ell_cfg(vals, idx, x, freeze(config))
+    """ELL spmv (config=None -> ``DEFAULT_CONFIG``)."""
+    return _ell_cfg(vals, idx, x, freeze(config or DEFAULT_CONFIG))
 
 
 @dataclass
@@ -135,12 +94,12 @@ def _spmv_binned(ell_vals, ell_idx, ell_rows, coo_rows, coo_cols, coo_vals,
 
 def spmv(m: BinnedCSR, x: jnp.ndarray, use_kernel: bool = True,
          config: Optional[Config] = None) -> jnp.ndarray:
-    """Binned spmv: ELL head via the tuned (config=None -> autotuned)
-    implementation, COO tail via segment-sum."""
+    """Binned spmv: ELL head via ``config`` (None -> ``DEFAULT_CONFIG``),
+    COO tail via segment-sum."""
     if not use_kernel:
         config = {"impl": "xla_ell"}
     elif config is None:
-        config = tuned_config(m.ell_vals, m.ell_idx, x)
+        config = DEFAULT_CONFIG
     return _spmv_binned(m.ell_vals, m.ell_idx, m.ell_rows, m.coo_rows,
                         m.coo_cols, m.coo_vals, x, m.n_rows,
                         freeze(config))

@@ -35,7 +35,9 @@ def spmv_ell_pallas(vals: jnp.ndarray, idx: jnp.ndarray, x: jnp.ndarray,
                     ) -> jnp.ndarray:
     """ELL spmv: vals/idx (R, K) with zero-padding, x (C,). Returns (R,).
 
-    Tunable knob (kernels/autotune.py): row_tile."""
+    Knob: row_tile.  Not autotuned: ops.DEFAULT_CONFIG is XLA's
+    gather + row-sum, which this kernel repeats with an extra (R, K)
+    array."""
     interpret = resolve_interpret(interpret)
     R, K = vals.shape
     row_tile = min(row_tile, max(R, 1))

@@ -165,6 +165,7 @@ class _WorkerSlot:
     load: float = 0.0                # last heartbeat-reported backlog
     hb_seq: int = 0
     stats: Dict[str, float] = field(default_factory=dict)
+    device: Dict[str, object] = field(default_factory=dict)
 
 
 class Router:
@@ -310,6 +311,12 @@ class Router:
     def worker_stats(self) -> Dict[str, Dict[str, float]]:
         with self._lock:
             return {n: dict(s.stats) for n, s in self._slots.items()}
+
+    def worker_devices(self) -> Dict[str, Dict[str, object]]:
+        """Each worker's last reported device (``transport.device_report``;
+        empty for workers that report none)."""
+        with self._lock:
+            return {n: dict(s.device) for n, s in self._slots.items()}
 
     def degraded(self) -> bool:
         with self._lock:
@@ -502,6 +509,7 @@ class Router:
                 return
             slot.load = float(msg.load)
             slot.stats = dict(msg.stats)
+            slot.device = dict(getattr(msg, "device", None) or {})
             slot.hb_seq += 1
             if slot.state != "alive":
                 # beats resumed: suspect/dead -> alive (rejoined).  Its

@@ -60,18 +60,44 @@ def chip_env(chip: Optional[int]) -> Dict[str, str]:
     host-wide libtpu lock.  ``chip=None``: the worker runs on the CPU
     (the extra workers of a fleet larger than the host's chip count).
     One chip belongs to one process: never give two workers the same
-    ``chip``."""
+    ``chip``.  The platforms are named, so a worker that cannot open its
+    chip fails at start-up instead of running on the CPU."""
     if chip is None:
         return {"JAX_PLATFORMS": "cpu"}
     import socket
     with socket.socket() as s:                 # a free slice-builder port
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    return {"TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+    return {"JAX_PLATFORMS": "tpu,cpu",
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
             "TPU_PROCESS_BOUNDS": "1,1,1",
             "TPU_VISIBLE_CHIPS": str(int(chip)),
             "TPU_PROCESS_PORT": str(port),
             "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
+
+
+def device_report() -> Dict[str, object]:
+    """The device this process's accel lane runs on, as JAX reports it,
+    plus the accelerator device files the process holds open: each chip
+    is its own device file, so workers given different chips hold
+    different files even where JAX numbers each one's chip 0."""
+    import stat
+
+    import jax
+    d = jax.devices()[0]
+    files = set()
+    fd_dir = "/proc/self/fd"
+    for fd in (os.listdir(fd_dir) if os.path.isdir(fd_dir) else ()):
+        try:
+            path = os.readlink(os.path.join(fd_dir, fd))
+            is_chr = stat.S_ISCHR(os.stat(path).st_mode)
+        except OSError:
+            continue
+        if is_chr and path.startswith(("/dev/accel", "/dev/vfio/")):
+            files.add(path)
+    return {"platform": d.platform, "kind": d.device_kind, "id": d.id,
+            "coords": list(getattr(d, "coords", None) or ()),
+            "files": sorted(files)}
 
 
 def _env_float(name: str, default: float) -> float:
@@ -124,11 +150,13 @@ class HeartbeatMsg:
     ``spans`` piggybacks the worker's drained trace events (plain
     dicts) so the router can stitch one fleet-wide timeline; an empty
     tuple when tracing is off or nothing happened since the last
-    beat."""
+    beat.  ``device`` is the worker's ``device_report()`` (empty for an
+    in-process worker)."""
     t: float
     load: float = 0.0
     stats: Dict[str, float] = field(default_factory=dict)
     spans: tuple = ()
+    device: Dict[str, object] = field(default_factory=dict)
 
 
 @dataclass(frozen=True)
@@ -513,6 +541,7 @@ def worker_main(argv=None) -> int:
     if args.lm:
         _register_lm(args.lm)
     sched = Scheduler()
+    device = device_report()
     rec = get_recorder()
     stop = threading.Event()
     slow = {"factor": 1.0, "until": 0.0}
@@ -531,7 +560,7 @@ def worker_main(argv=None) -> int:
         # (the final beat below ships the tail)
         send(HeartbeatMsg(time.monotonic(), load=float(st.in_flight),
                           stats=st.snapshot(),
-                          spans=tuple(rec.drain())))
+                          spans=tuple(rec.drain()), device=device))
         # keep the shared merge-on-write store fresh for peers and for
         # cold workers joining the fleet (zero-probe contract)
         get_calibration_cache().flush()

@@ -116,9 +116,8 @@ def make_share_spec(n: int = 2048, density: float = 0.01, seed: int = 0
                     jnp.asarray(cc.astype(np.int32)),
                     jnp.asarray(block[rr, cc]))
         if group == "accel":
-            # config=None -> per-(backend, shape-bucket) autotuned ELL
-            # implementation; searches land in the executor's warmup /
-            # calibration probes (then the disk cache), not steady state
+            # ELL head through XLA's gather + row-sum (spmv ops
+            # DEFAULT_CONFIG), COO tail below on the host lane
             parts = [spmv_ops.spmv(m_, x) for m_ in _prep_cache[key]]
             y = jnp.concatenate(parts)
         else:

@@ -96,12 +96,62 @@ def test_interpret_flag_and_tune_key_follow_default_device(
 def test_chip_env_gives_each_worker_one_chip():
     from repro.serve.transport import chip_env
     env = chip_env(2)
+    # the platforms are named: a chip that fails to open is an error,
+    # not a quiet fall back to the CPU
+    assert env["JAX_PLATFORMS"] == "tpu,cpu"
     assert env["TPU_VISIBLE_CHIPS"] == "2"
     assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
     assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
     assert env["TPU_PROCESS_ADDRESSES"] == f"localhost:{env['TPU_PROCESS_PORT']}"
     # a worker beyond the host's chips runs on the CPU
     assert chip_env(None) == {"JAX_PLATFORMS": "cpu"}
+
+
+def test_device_report_names_this_process_device():
+    from repro.serve.transport import device_report
+    rep = device_report()
+    d = jax.devices()[0]
+    assert (rep["platform"], rep["kind"], rep["id"]) == (
+        d.platform, d.device_kind, d.id)
+    # the CPU opens no accelerator device file
+    assert rep["files"] == []
+
+
+def test_router_keeps_each_workers_reported_device():
+    """Workers report their device in every heartbeat; the router
+    keeps the latest, so a fleet can prove each worker's chip."""
+    from repro.serve.router import Router
+    from repro.serve.transport import HeartbeatMsg
+
+    class Beating:
+        transport_alive = True
+
+        def __init__(self, name):
+            self.name = name
+
+        def start(self, on_result, on_heartbeat):
+            self.on_heartbeat = on_heartbeat
+
+        def submit(self, msg):
+            return False
+
+        def shutdown(self, timeout=10.0):
+            pass
+
+    ws = [Beating("a"), Beating("b")]
+    router = Router(ws, hb_timeout_s=60.0).start()
+    try:
+        for i, w in enumerate(ws):
+            w.on_heartbeat(w.name, HeartbeatMsg(
+                0.0, device={"platform": "tpu", "files": [f"/dev/vfio/{i}"]}))
+        devs = router.worker_devices()
+        assert devs["a"]["files"] == ["/dev/vfio/0"]
+        assert devs["b"]["files"] == ["/dev/vfio/1"]
+        # a heartbeat without a device report leaves the entry empty
+        ws[0].on_heartbeat("a", HeartbeatMsg(0.0))
+        assert router.worker_devices()["a"] == {}
+    finally:
+        router.shutdown(timeout=5)
 
 
 def test_router_process_imports_no_jax():

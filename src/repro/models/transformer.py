@@ -59,10 +59,7 @@ def lm_forward(params, inputs, cfg, *, tp: int = 1, make_cache_len: int = 0,
     x, caches, aux = blocks.apply_stack(
         params["stack"], x, cfg, sin=sin, cos=cos, kv_repeat=kv_rep,
         make_cache_len=make_cache_len)
-    x = norm(params["final_norm"], x, cfg)
-    logits = unembed(params.get("unembed"), x, cfg,
-                     embed_params=params["embed"])
-    logits = shard_act(logits, ("batch", None, "vocab"))
+    logits = shard_act(_head(params, x, cfg), ("batch", None, "vocab"))
     return logits, caches, aux
 
 
@@ -85,7 +82,12 @@ def lm_decode_step(params, inputs, cfg, caches, position, *, tp: int = 1):
     x, new_caches, _ = blocks.apply_stack_decode(
         params["stack"], x, cfg, caches, position, sin=sin, cos=cos,
         kv_repeat=kv_rep)
-    x = norm(params["final_norm"], x, cfg)
-    logits = unembed(params.get("unembed"), x, cfg,
-                     embed_params=params["embed"])
-    return logits, new_caches
+    return _head(params, x, cfg), new_caches
+
+
+def _head(params, x, cfg):
+    """Final norm and unembedding: logits over the vocabulary."""
+    with jax.named_scope("head"):
+        x = norm(params["final_norm"], x, cfg)
+        return unembed(params.get("unembed"), x, cfg,
+                       embed_params=params["embed"])

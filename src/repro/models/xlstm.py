@@ -164,6 +164,11 @@ def _group_norm(h, scale, nh, eps=1e-6):
 
 def mlstm_block(params, x, cfg, *, make_cache: bool = False, decode_state=None):
     """x: (B,T,d). If decode_state is given, runs the recurrent path."""
+    with jax.named_scope("mlstm"):
+        return _mlstm_block(params, x, cfg, make_cache, decode_state)
+
+
+def _mlstm_block(params, x, cfg, make_cache, decode_state):
     d_inner, nh, dh = _mdims(cfg)
     B, T, _ = x.shape
     xz = linear(params["up"], x)
@@ -232,6 +237,11 @@ def init_slstm_block(key, cfg):
 
 def slstm_block(params, x, cfg, state=None):
     """x: (B,T,d). Sequential scan (recurrent gate connections)."""
+    with jax.named_scope("slstm"):
+        return _slstm_block(params, x, cfg, state)
+
+
+def _slstm_block(params, x, cfg, state):
     B, T, d = x.shape
     nh = cfg.n_heads
     dh = d // nh

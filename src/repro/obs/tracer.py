@@ -4,9 +4,13 @@ Design constraints (see obs/README.md for the span taxonomy):
 
 * **Cheap when off.**  ``REPRO_TRACE=0`` turns every record call into a
   single attribute check + early return; nothing is allocated, no lock
-  is taken.  The overhead contract the bench gates on (traced p50 <=
-  1.05x untraced) only holds because the *on* path is also tiny: one
-  dict build + deque append under a lock.
+  is taken.  The *on* path is also small: one dict build + deque append
+  under a lock, and for ``span()`` one profiler annotation.
+* **On the profiler's timeline.**  While enabled, ``span()`` also enters
+  ``jax.profiler.TraceAnnotation(name)`` for its scope, so a
+  ``jax.profiler`` trace shows the span on the thread that ran it, on
+  the profiler's clock, beside the device ops.  JAX is used only if the
+  process has already imported it: the router's process never does.
 * **Bounded memory.**  Events land in a ``deque(maxlen=REPRO_TRACE_BUF)``
   (default 65536): a week-long serving run can leave tracing on and the
   buffer stays a ring, dropping the oldest spans.
@@ -30,10 +34,11 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Dict, Iterable, List, Optional
 
 
@@ -117,13 +122,21 @@ class TraceRecorder:
     @contextmanager
     def span(self, name: str, cat: str, track: str,
              trace_id: Optional[str] = None, **attrs):
-        """Context-manager form of ``complete`` for inline scopes."""
+        """Record the scope as a span, and annotate it in the profiler's
+        trace.  Yields the span's args: what the scope learns (a batch's
+        row count, a placement's decision) is added to it in place and
+        recorded when the scope ends.  ``complete`` is for intervals
+        known only after the fact."""
         if not self.enabled:
-            yield
+            yield attrs
             return
+        jax = sys.modules.get("jax")
+        annotation = (jax.profiler.TraceAnnotation(name)
+                      if jax is not None else nullcontext())
         t0 = time.monotonic()
         try:
-            yield
+            with annotation:
+                yield attrs
         finally:
             self.complete(name, cat, t0, time.monotonic(), track,
                           trace_id, **attrs)

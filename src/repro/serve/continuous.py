@@ -49,9 +49,11 @@ Steppers are duck-typed; the engine needs::
 from __future__ import annotations
 
 import collections
+import sys
 import threading
 import time
 import weakref
+from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional
 
 from repro.obs import get_recorder
@@ -110,7 +112,19 @@ class ContinuousEngine:
     (keeps the accounting invariant: every submitted request is
     completed/failed exactly once); ``hooks`` may carry ``on_step``,
     ``on_join``, ``on_evict``, ``on_cancel``, ``on_preempt`` counters
-    (called outside locks).
+    (called outside locks) and ``on_busy(group, seconds)``, which is
+    given the time of each prefill, insert and decode call on its lane.
+
+    Spans (``repro.obs``): ``prefill`` and ``engine_step`` on the
+    ``engine:<workload>`` track cover a phase from before its lane
+    locks are taken until after they are released; their children
+    tile them: ``lane_wait`` (the locks), then ``prefill_call`` or each
+    joined row's ``engine_insert`` and the ``decode`` call, on the
+    ``lane:<group>`` track of the lane that ran them.  Every child of
+    step ``k`` carries ``step=k``; ``engine_boundary`` covers the host
+    work between two steps.  While the recorder is on, ``init_slots``,
+    each insert and each step wait for their outputs before their spans
+    close, so the spans time the work and not its dispatch.
 
     ``should_yield()`` (optional) is polled at every step boundary:
     while it returns True — the Scheduler dispatched latency-class
@@ -170,8 +184,24 @@ class ContinuousEngine:
         self.cancellations = 0
         self.preemptions = 0
         self.max_live = 0
-        with self._step_ctx():
-            self._state = stepper.init_slots()
+        self.init_s: Optional[float] = None
+        traced = self._rec.enabled
+        for lk in self.step_locks:
+            lk.acquire()
+        try:
+            t0 = time.monotonic()
+            with self._rec.span("engine_init", "engine",
+                                f"lane:{decode_group}", group=decode_group):
+                with self._step_ctx():
+                    self._state = stepper.init_slots()
+                    if traced:
+                        _sync(self._state)
+            if traced:
+                # untraced, this would time the dispatch alone
+                self.init_s = time.monotonic() - t0
+        finally:
+            for lk in reversed(self.step_locks):
+                lk.release()
         self._threads = [
             threading.Thread(target=self._prefill_loop, daemon=True,
                              name=f"serve-cb-{_safe(self.workload)}-prefill"),
@@ -194,6 +224,7 @@ class ContinuousEngine:
 
     # ---- prefill lane ----------------------------------------------------
     def _prefill_loop(self) -> None:
+        rec = self._rec
         while True:
             with self._cv:
                 while not self._inbox and not self._stop:
@@ -201,21 +232,25 @@ class ContinuousEngine:
                 if self._stop and not self._inbox:
                     return
                 pending = self._inbox.popleft()
+            trace_id = getattr(pending.req, "trace_id", None)
             try:
-                t_p0 = self._rec.now()
-                for lk in self.prefill_locks:
-                    lk.acquire()
-                try:
-                    with self._prefill_ctx():
-                        rows = self.stepper.prefill(pending.spec)
-                finally:
-                    for lk in reversed(self.prefill_locks):
-                        lk.release()
-                self._rec.complete(
-                    "prefill", "engine", t_p0, self._rec.now(),
-                    self._track,
-                    getattr(pending.req, "trace_id", None),
-                    workload=self.workload, group=self.prefill_group)
+                with rec.span("prefill", "engine", self._track, trace_id,
+                              workload=self.workload,
+                              group=self.prefill_group):
+                    with rec.span("lane_wait", "engine", self._track,
+                                  trace_id, phase="prefill",
+                                  group=self.prefill_group):
+                        for lk in self.prefill_locks:
+                            lk.acquire()
+                    try:
+                        with self._prefill_ctx(), self._lane_span(
+                                "prefill_call", self.prefill_group,
+                                trace_id) as args:
+                            rows = self.stepper.prefill(pending.spec)
+                            args["rows"] = len(rows)
+                    finally:
+                        for lk in reversed(self.prefill_locks):
+                            lk.release()
                 pending.req.future.meta.setdefault(
                     "t_first_token", self._clock())
                 pending.req.future.meta.setdefault("engine", {
@@ -230,119 +265,164 @@ class ContinuousEngine:
             except BaseException as exc:          # noqa: BLE001
                 self._reject(pending.req, exc)
 
+    @contextmanager
+    def _lane_span(self, name: str, group: str,
+                   trace_id: Optional[str] = None, **attrs):
+        """A span of work on ``group``'s lane, on its ``lane:<group>``
+        track; its time also goes to the ``on_busy`` hook."""
+        t0 = time.monotonic()
+        with self._rec.span(name, "engine", f"lane:{group}", trace_id,
+                            **attrs) as args:
+            yield args
+        busy = self._hooks.get("on_busy")
+        if busy is not None:
+            busy(group, time.monotonic() - t0)
+
     # ---- decode lane -----------------------------------------------------
     def _step_loop(self) -> None:
+        live_now: Dict[int, _Row] = {}
+        joined: List[tuple] = []
         while True:
-            joined, evicted, cancelled = [], [], []
-            with self._cv:
-                while (not self._ready and not self._live
-                       and not self._stop):
-                    self._cv.wait()
-                if self._stop and not self._ready and not self._live:
-                    return
-                # join at the step boundary: fill free slots from ready
-                while self._ready and self._free:
-                    row, row_state = self._ready.popleft()
-                    if row.pending.req.future.done():
-                        # already resolved elsewhere (hedge winner,
-                        # shutdown rejection): never takes a slot
-                        self.cancellations += 1
-                        cancelled.append(row)
-                        continue
-                    row.slot = self._free.pop()
-                    self._live[row.slot] = row
-                    joined.append((row, row_state))
-                live_now = dict(self._live)
-                self.max_live = max(self.max_live, len(live_now))
-                if cancelled:
-                    self._cv.notify_all()
-            if cancelled:
-                if self._rec.enabled:
-                    for row in cancelled:
-                        self._rec.instant(
-                            "engine_cancel", "engine", self._track,
-                            getattr(row.pending.req, "trace_id", None),
-                            at="join")          # preempted before a slot
-                if "on_cancel" in self._hooks:
-                    self._hooks["on_cancel"](len(cancelled))
-            cancelled = []
             if not live_now:
-                continue
-
+                # nothing live: wait for arrivals, outside any span
+                with self._cv:
+                    while (not self._ready and not self._live
+                           and not self._stop):
+                        self._cv.wait()
+                    if self._stop and not self._ready and not self._live:
+                        return
+                live_now, joined = self._join()
+                if not live_now:
+                    continue
             self._maybe_yield(live_now)
-            t_s0 = self._rec.now()
-            for lk in self.step_locks:
-                lk.acquire()
-            try:
-                with self._step_ctx():
-                    for row, row_state in joined:
-                        self._state = self.stepper.insert(
-                            self._state, row.slot, row_state)
-                        self.joins += 1
-                    self._state, outs = self.stepper.step(self._state)
-                self.steps += 1
-            finally:
-                for lk in reversed(self.step_locks):
-                    lk.release()
-            # span covers lock wait too: lane contention is exactly
-            # what a step timeline should show
-            self._rec.complete("engine_step", "engine", t_s0,
-                               self._rec.now(), self._track,
-                               n_live=len(live_now), joins=len(joined),
-                               group=self.decode_group)
-            if joined:
-                if self._rec.enabled:
-                    for row, _ in joined:
-                        self._rec.instant(
-                            "engine_join", "engine", self._track,
-                            getattr(row.pending.req, "trace_id", None),
-                            slot=row.slot)
-                if "on_join" in self._hooks:
+            k = self.steps
+            outs = self._run_step(k, live_now, joined)
+            with self._rec.span("engine_boundary", "engine", self._track,
+                                step=k) as args:
+                if joined and "on_join" in self._hooks:
                     self._hooks["on_join"](len(joined))
-            if "on_step" in self._hooks:
-                self._hooks["on_step"](len(live_now))
+                if "on_step" in self._hooks:
+                    self._hooks["on_step"](len(live_now))
+                args["evicted"] = self._retire(live_now, outs)
+                live_now, joined = self._join()
+                args["joined_next"] = len(joined)
 
-            for slot, row in live_now.items():
+    def _join(self):
+        """The step boundary's join: fill free slots from ready rows.
+        Returns the rows live for the next step and those that joined."""
+        joined, cancelled = [], []
+        with self._cv:
+            while self._ready and self._free:
+                row, row_state = self._ready.popleft()
                 if row.pending.req.future.done():
-                    # hedge loser / cancelled mid-decode: free the slot
-                    # at this boundary, skip finish (resolve-exactly-
-                    # once makes the duplicate's value the only value)
+                    # already resolved elsewhere (hedge winner,
+                    # shutdown rejection): never takes a slot
+                    self.cancellations += 1
                     cancelled.append(row)
                     continue
-                if outs is not None:
-                    row.collected.append(outs[slot])
-                row.remaining -= 1
-                if row.remaining <= 0:
-                    evicted.append(row)
-            if not evicted and not cancelled:
-                continue
-            with self._cv:
-                for row in evicted:
-                    del self._live[row.slot]
-                    self._free.append(row.slot)
-                    self.evictions += 1
-                for row in cancelled:
-                    del self._live[row.slot]
-                    self._free.append(row.slot)
-                    self.cancellations += 1
+                row.slot = self._free.pop()
+                self._live[row.slot] = row
+                joined.append((row, row_state))
+            live_now = dict(self._live)
+            self.max_live = max(self.max_live, len(live_now))
+            if cancelled:
                 self._cv.notify_all()
+        if cancelled:
             if self._rec.enabled:
-                for row in evicted:
-                    self._rec.instant(
-                        "engine_evict", "engine", self._track,
-                        getattr(row.pending.req, "trace_id", None),
-                        slot=row.slot)
                 for row in cancelled:
                     self._rec.instant(
                         "engine_cancel", "engine", self._track,
                         getattr(row.pending.req, "trace_id", None),
-                        at="mid_decode")        # preempted from a slot
-            if evicted and "on_evict" in self._hooks:
-                self._hooks["on_evict"](len(evicted))
-            if cancelled and "on_cancel" in self._hooks:
+                        at="join")          # preempted before a slot
+            if "on_cancel" in self._hooks:
                 self._hooks["on_cancel"](len(cancelled))
+        return live_now, joined
+
+    def _run_step(self, k: int, live_now: Dict[int, _Row],
+                  joined: List[tuple]):
+        """Step ``k``: the joined rows' inserts and one batched decode
+        call on the decode lane; returns the step's outputs."""
+        rec = self._rec
+        group = self.decode_group
+        # the span covers lock wait too: lane contention is exactly
+        # what a step timeline should show
+        with rec.span("engine_step", "engine", self._track, step=k,
+                      n_live=len(live_now), joins=len(joined), group=group):
+            with rec.span("lane_wait", "engine", self._track, step=k,
+                          phase="step", group=group):
+                for lk in self.step_locks:
+                    lk.acquire()
+            try:
+                with self._step_ctx():
+                    for row, row_state in joined:
+                        with self._lane_span(
+                                "engine_insert", group,
+                                getattr(row.pending.req, "trace_id", None),
+                                slot=row.slot, step=k) as args:
+                            self._state = self.stepper.insert(
+                                self._state, row.slot, row_state)
+                            if rec.enabled:
+                                _sync(self._state)
+                                args["bytes"] = _nbytes(row_state)
+                        self.joins += 1
+                    with self._lane_span("decode", group, step=k,
+                                         n_live=len(live_now)):
+                        self._state, outs = self.stepper.step(self._state)
+                        if rec.enabled:
+                            _sync(self._state)
+                self.steps += 1
+            finally:
+                for lk in reversed(self.step_locks):
+                    lk.release()
+        return outs
+
+    def _retire(self, live_now: Dict[int, _Row], outs) -> int:
+        """Collect a step's outputs; evict and finish the rows that are
+        done and free the slots of cancelled ones.  Returns the number
+        evicted."""
+        evicted, cancelled = [], []
+        for slot, row in live_now.items():
+            if row.pending.req.future.done():
+                # hedge loser / cancelled mid-decode: free the slot
+                # at this boundary, skip finish (resolve-exactly-
+                # once makes the duplicate's value the only value)
+                cancelled.append(row)
+                continue
+            if outs is not None:
+                row.collected.append(outs[slot])
+            row.remaining -= 1
+            if row.remaining <= 0:
+                evicted.append(row)
+        if not evicted and not cancelled:
+            return 0
+        with self._cv:
             for row in evicted:
-                self._finish_row(row)
+                del self._live[row.slot]
+                self._free.append(row.slot)
+                self.evictions += 1
+            for row in cancelled:
+                del self._live[row.slot]
+                self._free.append(row.slot)
+                self.cancellations += 1
+            self._cv.notify_all()
+        if self._rec.enabled:
+            for row in evicted:
+                self._rec.instant(
+                    "engine_evict", "engine", self._track,
+                    getattr(row.pending.req, "trace_id", None),
+                    slot=row.slot)
+            for row in cancelled:
+                self._rec.instant(
+                    "engine_cancel", "engine", self._track,
+                    getattr(row.pending.req, "trace_id", None),
+                    at="mid_decode")        # preempted from a slot
+        if evicted and "on_evict" in self._hooks:
+            self._hooks["on_evict"](len(evicted))
+        if cancelled and "on_cancel" in self._hooks:
+            self._hooks["on_cancel"](len(cancelled))
+        for row in evicted:
+            self._finish_row(row)
+        return len(evicted)
 
     def _maybe_yield(self, live_now: Dict[int, _Row]) -> None:
         """Iteration-boundary preemption: pause (bounded) while the
@@ -415,18 +495,38 @@ class ContinuousEngine:
             t.join(timeout)
 
     def snapshot(self) -> Dict[str, object]:
+        """Counters; ``init_s`` (the ``engine_init`` span's seconds) only
+        when the recorder was on at construction."""
         with self._cv:
-            return {"workload": self.workload, "steps": self.steps,
+            snap = {"workload": self.workload, "steps": self.steps,
                     "joins": self.joins, "evictions": self.evictions,
                     "cancellations": self.cancellations,
                     "preemptions": self.preemptions,
                     "max_live": self.max_live, "live": len(self._live),
                     "prefill_group": self.prefill_group,
                     "decode_group": self.decode_group}
+        if self.init_s is not None:
+            snap["init_s"] = self.init_s
+        return snap
 
 
 def _safe(name: str) -> str:
     return name.replace("/", "-").replace("@", "-")
+
+
+def _sync(tree) -> None:
+    """Wait until the arrays of ``tree`` are computed (dispatch is
+    asynchronous, on the host's XLA too)."""
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.block_until_ready(tree)
+
+
+def _nbytes(tree) -> int:
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return 0
+    return int(sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(tree)))
 
 
 # ---------------------------------------------------------------------------
@@ -474,6 +574,7 @@ class LMStepper:
         L, tp_ = self.cache_len, tp
 
         @jax.jit
+        @jax.named_scope("prefill")
         def _prefill(params, prompt):
             logits, caches = model_zoo.prefill(
                 cfg, params, {"tokens": prompt}, cache_len=L, tp=tp_)

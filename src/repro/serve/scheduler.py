@@ -765,34 +765,17 @@ class Scheduler:
             return
         n_units = sum(max(int(s.total_units), 1) for s in specs)
         now = self.clock()
-        t_p0 = rec.now()
-
-        with self._lock:
-            loads = [GroupLoad(ld.name,
-                               self._unit_time(specs[0], ld.name),
-                               ld.busy_until, ld.alive)
-                     for ld in self._loads.values()]
-        if self.policy == "fifo":
-            loads = [ld for ld in loads if ld.name == self.fifo_group]
-        # contention pricing resolved per workload class: host-native
-        # adapters (lane_class "host", e.g. numpy sort) measured a
-        # near-1.0 factor where jax-jax pairs measure ~2 on a
-        # no-headroom box — the class factor is what lets exactly
-        # those co-schedules through
-        factor = self.span_factors.get(
-            getattr(specs[0], "lane_class", "jax"), self.shared_span_factor)
-        decision = plan_placement(
-            n_units, loads, now,
-            split_overhead_s=self.split_overhead_s,
-            # a coalesced batch's units are whole requests — sharing
-            # them is exactly co-scheduling, allowed; single tiny
-            # requests may still prefer a dedicated lane on their own
-            allow_shared=(self.policy == "cost" and len(loads) >= 2),
-            shared_span_factor=factor,
-            # the same measured headroom prices dedicated spans that
-            # overlap other busy lanes (no-headroom hosts: two
-            # "parallel" dedicated lanes are contention, not overlap)
-            contention_factor=factor)
+        with rec.span("placement", "request", "sched", batch[0].trace_id,
+                      workload=specs[0].workload,
+                      n_batch=len(batch)) as args:
+            decision = self._place(specs, n_units, now)
+            if decision is not None and rec.enabled:
+                args.update(
+                    kind=decision.kind, groups=list(decision.groups),
+                    est_exec_s=decision.est_exec_s,
+                    queued_behind_s=decision.queued_behind_s,
+                    alternatives={k: round(v, 6) for k, v
+                                  in decision.alternatives.items()})
         if decision is None:
             # every lane is dead: a structured *rejection*, counted as
             # one (a Rejection delivered to the caller while `failed`
@@ -804,18 +787,6 @@ class Scheduler:
                     with self._idle:
                         self._idle.notify_all()
             return
-        decision = self._maybe_explore(specs[0].workload, loads, decision,
-                                       n_units, now)
-        if rec.enabled:
-            rec.complete(
-                "placement", "request", t_p0, rec.now(), "sched",
-                batch[0].trace_id, workload=specs[0].workload,
-                kind=decision.kind, groups=list(decision.groups),
-                est_exec_s=decision.est_exec_s,
-                queued_behind_s=decision.queued_behind_s,
-                n_batch=len(batch),
-                alternatives={k: round(v, 6) for k, v
-                              in decision.alternatives.items()})
 
         # deadline-based shedding at admission: LATENCY-class members
         # whose deadline the projected completion already misses are
@@ -876,6 +847,40 @@ class Scheduler:
             self._lanes[_SHARED_LANE].put(ex)
         else:
             self._lanes[decision.groups[0]].put(ex)
+
+    def _place(self, specs, n_units: int, now: float):
+        """The placement decision for one batch (None when no lane is
+        alive)."""
+        with self._lock:
+            loads = [GroupLoad(ld.name,
+                               self._unit_time(specs[0], ld.name),
+                               ld.busy_until, ld.alive)
+                     for ld in self._loads.values()]
+        if self.policy == "fifo":
+            loads = [ld for ld in loads if ld.name == self.fifo_group]
+        # contention pricing resolved per workload class: host-native
+        # adapters (lane_class "host", e.g. numpy sort) measured a
+        # near-1.0 factor where jax-jax pairs measure ~2 on a
+        # no-headroom box — the class factor is what lets exactly
+        # those co-schedules through
+        factor = self.span_factors.get(
+            getattr(specs[0], "lane_class", "jax"), self.shared_span_factor)
+        decision = plan_placement(
+            n_units, loads, now,
+            split_overhead_s=self.split_overhead_s,
+            # a coalesced batch's units are whole requests — sharing
+            # them is exactly co-scheduling, allowed; single tiny
+            # requests may still prefer a dedicated lane on their own
+            allow_shared=(self.policy == "cost" and len(loads) >= 2),
+            shared_span_factor=factor,
+            # the same measured headroom prices dedicated spans that
+            # overlap other busy lanes (no-headroom hosts: two
+            # "parallel" dedicated lanes are contention, not overlap)
+            contention_factor=factor)
+        if decision is None:
+            return None
+        return self._maybe_explore(specs[0].workload, loads, decision,
+                                   n_units, now)
 
     def _maybe_explore(self, wl: str, loads, decision: PlacementDecision,
                        n_units: int, now: float) -> PlacementDecision:
@@ -996,7 +1001,9 @@ class Scheduler:
                 should_yield=should_yield,
                 hooks={"on_step": on_step, "on_join": on_join,
                        "on_evict": on_evict, "on_cancel": on_cancel,
-                       "on_preempt": on_preempt},
+                       "on_preempt": on_preempt,
+                       # the engine's lane time is lane busy time too
+                       "on_busy": self.audit.lane_busy},
                 clock=self.clock)
             self._engines[key] = eng
             self.engine_placements[stepper.workload] = plan
@@ -1193,7 +1200,7 @@ class Scheduler:
                 kept.append(i)
         return kept
 
-    def _merge_batch(self, ex: _Execution, kept: List[int]):
+    def _merge_batch(self, ex: _Execution, kept: List[int], track: str):
         """Array-level batching: when every kept member's adapter has a
         ``merge`` hook, stack the payloads into ONE execution (returns
         the ``MergedBatch``, or None -> request-granularity path).  A
@@ -1208,7 +1215,10 @@ class Scheduler:
                                 for s in specs):
             return None
         try:
-            merged = merge(specs)
+            with self._rec.span("merge", "exec", track,
+                                ex.requests[kept[0]].trace_id, n=len(kept),
+                                workload=specs[0].workload):
+                merged = merge(specs)
         except Exception:                          # noqa: BLE001
             return None
         if merged is not None:
@@ -1229,41 +1239,30 @@ class Scheduler:
         try:
             with self._device_ctx(g):
                 self._fault_pre(faults)
-                t_m0 = rec.now()
-                merged = self._merge_batch(ex, kept)
+                merged = self._merge_batch(ex, kept, track)
                 if merged is not None:
-                    rec.complete("merge", "exec", t_m0, rec.now(), track,
-                                 ex.requests[kept[0]].trace_id,
-                                 n=len(kept), workload=cal_wl)
+                    trace_id = ex.requests[kept[0]].trace_id
                     cal_wl = merged.spec.workload
                     ts = self.clock()
-                    t_e0 = rec.now()
-                    value = merged.spec.run_one()
-                    t_e1 = rec.now()
+                    with rec.span("lane_exec", "exec", track, trace_id,
+                                  workload=cal_wl, merged=True,
+                                  n=len(kept)):
+                        value = merged.spec.run_one()
                     done_units += max(int(merged.spec.total_units), 1)
-                    rec.complete("lane_exec", "exec", t_e0, t_e1, track,
-                                 ex.requests[kept[0]].trace_id,
-                                 workload=cal_wl, merged=True,
-                                 n=len(kept))
-                    t_d0 = rec.now()
-                    for j, i in enumerate(kept):
-                        self._resolve(ex.requests[i],
-                                      merged.demux(value, j), ts,
-                                      hedge=ex.hedge)
-                    rec.complete("demux", "exec", t_d0, rec.now(), track,
-                                 ex.requests[kept[0]].trace_id,
-                                 n=len(kept))
+                    with rec.span("demux", "exec", track, trace_id,
+                                  n=len(kept)):
+                        for j, i in enumerate(kept):
+                            self._resolve(ex.requests[i],
+                                          merged.demux(value, j), ts,
+                                          hedge=ex.hedge)
                     kept = []
                 for i in kept:
                     r, spec = ex.requests[i], ex.specs[i]
                     ts = self.clock()
-                    t_e0 = rec.now()
-                    value = spec.run_one()
-                    t_e1 = rec.now()
+                    with rec.span("lane_exec", "exec", track, r.trace_id,
+                                  workload=r.workload, hedge=ex.hedge):
+                        value = spec.run_one()
                     done_units += max(int(spec.total_units), 1)
-                    rec.complete("lane_exec", "exec", t_e0, t_e1, track,
-                                 r.trace_id, workload=r.workload,
-                                 hedge=ex.hedge)
                     self._resolve(r, value, ts, hedge=ex.hedge)
             # an injected slowdown stretches elapsed (below) so the
             # slowed time is what calibration learns — survivors'
@@ -1294,11 +1293,9 @@ class Scheduler:
             if len(kept) == 1:
                 r = ex.requests[kept[0]]
                 spec = ex.specs[kept[0]]
-                t_e0 = rec.now()
-                value = self._run_shared_single(spec)
-                rec.complete("lane_exec", "exec", t_e0, rec.now(),
-                             "lane:shared", r.trace_id,
-                             workload=r.workload, shared=True)
+                with rec.span("lane_exec", "exec", "lane:shared",
+                              r.trace_id, workload=r.workload, shared=True):
+                    value = self._run_shared_single(spec)
                 self._resolve(r, value, t0)
             else:
                 self._run_shared_batch(ex, kept, t0)
@@ -1355,21 +1352,18 @@ class Scheduler:
         hx.calibrate(lambda g, k: run_share(g, 0, k), probe_units=1,
                      workload=key, unit_cost=uc, probe=False)
         rec = self._rec
-        t_e0 = rec.now()
+        trace_id = ex.requests[kept[0]].trace_id
         # min_units=1: every live group keeps measuring its own batch
         # throughput (a stale slow estimate must not starve a lane out
         # of the split it would need to correct itself)
-        out = hx.run_work_shared(key, len(specs), run_share, combine,
-                                 comm_cost=spec0.comm_cost, warmup=False,
-                                 min_units=1)
-        rec.complete("lane_exec", "exec", t_e0, rec.now(), "lane:shared",
-                     ex.requests[kept[0]].trace_id, workload=key,
-                     shared=True, n=len(kept))
-        t_d0 = rec.now()
-        for j, i in enumerate(kept):
-            self._resolve(ex.requests[i], out.value[j], t0)
-        rec.complete("demux", "exec", t_d0, rec.now(), "lane:shared",
-                     ex.requests[kept[0]].trace_id, n=len(kept))
+        with rec.span("lane_exec", "exec", "lane:shared", trace_id,
+                      workload=key, shared=True, n=len(kept)):
+            out = hx.run_work_shared(key, len(specs), run_share, combine,
+                                     comm_cost=spec0.comm_cost,
+                                     warmup=False, min_units=1)
+        with rec.span("demux", "exec", "lane:shared", trace_id, n=len(kept)):
+            for j, i in enumerate(kept):
+                self._resolve(ex.requests[i], out.value[j], t0)
 
     def _resolve(self, req: Request, value, t_start: float,
                  hedge: bool = False) -> None:

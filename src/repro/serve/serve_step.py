@@ -94,6 +94,7 @@ def make_slot_step(cfg: ArchConfig, *, tp: int = 1):
         return axes
 
     @jax.jit
+    @jax.named_scope("decode")
     def slot_step(params, tokens, slot_caches, positions):
         axes = _axes(slot_caches)
         return jax.vmap(_row, in_axes=(None, 0, axes, 0),

@@ -7,6 +7,7 @@ stackable; accounting stays exact under step-quantum dispatch; a
 fresh process places the engine's lanes with zero probe runs; and
 the hist/conv merge hooks stack same-bucket requests exactly.
 """
+import collections
 import threading
 import time
 
@@ -257,6 +258,164 @@ def test_engine_shutdown_finishes_in_flight(lm):
         except RequestRejected:
             pass                         # structured shutdown rejection
     assert sched.stats.in_flight == 0
+
+
+# ---------------------------------------------------------------------------
+# tracing: the engine's phases on the recorder's timeline
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def live_recorder():
+    """The process-wide recorder, cleared and on for the test."""
+    from repro.obs import get_recorder
+    rec = get_recorder()
+    was = rec.enabled
+    rec.enabled = True
+    rec.clear()
+    yield rec
+    rec.enabled = was
+    rec.clear()
+
+
+class _SleepStepper:
+    """Engine protocol with sleeps for work: each row echoes its spec."""
+
+    workload = "sleepy"
+    n_slots = 4
+
+    def __init__(self, n_steps=4, dt=0.004):
+        self.n_steps, self.dt = n_steps, dt
+
+    def init_slots(self):
+        time.sleep(self.dt)
+        return [None] * self.n_slots
+
+    def prefill(self, spec):
+        time.sleep(self.dt)
+        return [(spec, spec, self.n_steps)]
+
+    def insert(self, state, slot, row_state):
+        time.sleep(self.dt / 2)
+        state = list(state)
+        state[slot] = row_state
+        return state
+
+    def step(self, state):
+        time.sleep(self.dt)
+        return state, list(state)
+
+    def finish(self, state, slot, first_out, collected):
+        return [first_out] + collected
+
+    def assemble(self, row_values):
+        return row_values[0]
+
+
+class _Req:
+    def __init__(self, trace_id):
+        import concurrent.futures
+        self.trace_id = trace_id
+        self.future = concurrent.futures.Future()
+        self.future.meta = {}
+
+
+def _serve_sleepy(n_requests=6, hooks=None):
+    """Requests through a ContinuousEngine over two lanes (prefill on
+    ``accel``, decode on ``host``), arriving over several steps."""
+    from repro.serve.continuous import ContinuousEngine
+
+    eng = ContinuousEngine(
+        _SleepStepper(),
+        resolve=lambda req, value, t: req.future.set_result(value),
+        reject=lambda req, exc: req.future.set_exception(exc),
+        prefill_locks=[threading.Lock()], step_locks=[threading.Lock()],
+        prefill_group="accel", decode_group="host", hooks=hooks)
+    reqs = [_Req(f"tid-{i}") for i in range(n_requests)]
+    try:
+        for i, r in enumerate(reqs):
+            assert eng.submit(r, i, time.monotonic())
+            if i % 2:
+                time.sleep(0.006)
+        for i, r in enumerate(reqs):
+            assert r.future.result(timeout=30)[0] == i
+    finally:
+        eng.shutdown()
+    return eng, reqs
+
+
+def test_engine_children_tile_their_phases(live_recorder):
+    """``lane_wait`` + ``engine_insert`` + ``decode`` tile each
+    ``engine_step`` and ``lane_wait`` + ``prefill_call`` each
+    ``prefill``, to within 1 ms; every child names its parent (the
+    step's index, the request's trace id); spans on one lane track never
+    overlap."""
+    eng, reqs = _serve_sleepy()
+    evs = [e for e in live_recorder.events() if e["ph"] == "X"]
+    by = collections.defaultdict(list)
+    for e in evs:
+        by[e["name"]].append(e)
+    steps = by["engine_step"]
+    assert len(steps) == eng.steps > 0
+    assert sum(e["args"]["joins"] for e in steps) == len(reqs)
+
+    def inside(child, parent):
+        return (child["ts"] >= parent["ts"] - 1.0 and child["ts"]
+                + child["dur"] <= parent["ts"] + parent["dur"] + 1.0)
+
+    for st in steps:
+        k = st["args"]["step"]
+        kids = [e for n in ("lane_wait", "engine_insert", "decode")
+                for e in by[n] if e["args"].get("step") == k]
+        assert all(inside(c, st) for c in kids)
+        names = sorted(c["name"] for c in kids)
+        assert names.count("decode") == names.count("lane_wait") == 1
+        assert names.count("engine_insert") == st["args"]["joins"]
+        assert st["dur"] - sum(c["dur"] for c in kids) <= 1000.0
+    for pre in by["prefill"]:
+        tid = pre["args"]["trace_id"]
+        kids = [e for n in ("lane_wait", "prefill_call") for e in by[n]
+                if e["args"].get("trace_id") == tid]
+        assert len(kids) == 2 and all(inside(c, pre) for c in kids)
+        assert pre["dur"] - sum(c["dur"] for c in kids) <= 1000.0
+    assert {e["args"]["step"] for e in by["engine_boundary"]} <= {
+        st["args"]["step"] for st in steps}
+    assert sum(e["args"]["evicted"] for e in by["engine_boundary"]) == len(
+        reqs)
+    for track in ("lane:accel", "lane:host"):
+        on = sorted((e for e in evs if e["track"] == track),
+                    key=lambda e: e["ts"])
+        assert on
+        for a, b in zip(on, on[1:]):
+            assert b["ts"] >= a["ts"] + a["dur"] - 1e-3, (a, b)
+    init = by["engine_init"]
+    assert len(init) == 1 and init[0]["track"] == "lane:host"
+    assert eng.snapshot()["init_s"] > 0
+
+
+def test_engine_insert_carries_trace_id(live_recorder):
+    """Each joined row's ``engine_insert`` names its request and slot;
+    the old ``engine_join`` instant is gone."""
+    _, reqs = _serve_sleepy(n_requests=3)
+    evs = live_recorder.events()
+    inserts = [e for e in evs if e["name"] == "engine_insert"]
+    assert sorted(e["args"]["trace_id"] for e in inserts) == sorted(
+        r.trace_id for r in reqs)
+    assert all(e["track"] == "lane:host" and "slot" in e["args"]
+               and "bytes" in e["args"] for e in inserts)
+    assert not any(e["name"] == "engine_join" for e in evs)
+
+
+def test_engine_lane_time_reaches_the_audit():
+    """The engine's prefill, insert and decode calls accrue to the
+    scheduler's placement audit, so ``resource_efficiency`` counts both
+    of the engine's lanes."""
+    sched = Scheduler(groups=_two_groups())
+    sched.submit("lbm", {"d": 8, "n_steps": 40, "seed": 5,
+                         "continuous": True}).result(timeout=300)
+    (plan,) = sched.engine_placements.values()
+    util = sched.audit.summary()["lane_utilization"]
+    sched.shutdown()
+    assert plan.prefill_group != plan.decode_group
+    assert util[plan.prefill_group] > 0 and util[plan.decode_group] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,62 @@ def test_recorder_is_thread_safe_under_concurrent_writers():
     assert len(rec) == 2000
 
 
+def test_span_reaches_the_profiler_trace(tmp_path):
+    """While the recorder is on, a span is also a host annotation in the
+    profiler's ``.xplane.pb``, under its own name."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    rec = TraceRecorder(enabled=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.span("obs_probe_span", "exec", "lane:host") as args:
+            jnp.arange(8.0).sum().block_until_ready()
+            args["n"] = 8
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "obs_probe_span" in names
+    (ev,) = rec.events()
+    assert ev["name"] == "obs_probe_span" and ev["args"] == {"n": 8}
+
+
+def test_disabled_recorder_serves_without_events_or_annotations(
+        monkeypatch):
+    """With the recorder off (``REPRO_TRACE=0``), serving an engine
+    request and a lane request records no event and constructs no
+    profiler annotation."""
+    import jax
+
+    from repro.core.hybrid_executor import DeviceGroup
+    from repro.serve.scheduler import Scheduler
+
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    rec = get_recorder()
+    monkeypatch.setattr(rec, "enabled", False)
+    rec.clear()
+    sched = Scheduler(groups=[DeviceGroup("accel", [], "accel"),
+                              DeviceGroup("host", [], "host")])
+    sched.submit("lbm", {"d": 8, "n_steps": 10, "seed": 5,
+                         "continuous": True}).result(timeout=300)
+    sched.submit("hist", {"n": 1 << 10, "n_bins": 16}).result(timeout=120)
+    sched.shutdown()
+    assert rec.events() == [] and made == []
+    # the count is live: the same class, entered by an enabled span
+    with TraceRecorder(enabled=True).span("probe", "exec", "t"):
+        pass
+    assert made == [("probe",)]
+
+
 # ---------------------------------------------------------------------------
 # propagation: wire pickle, router failover, engine preemption
 # ---------------------------------------------------------------------------

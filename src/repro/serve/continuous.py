@@ -40,7 +40,10 @@ Steppers are duck-typed; the engine needs::
     decode_cost   CostTerms for one batched step
     init_slots()            -> state
     prefill(spec)           -> [(row_state, first_out, n_steps), ...]
-    insert(state, slot, row_state) -> state
+    insert(state, slot, row_state) -> state   # consumes ``state``: its
+    #                                            arrays may be donated to
+    #                                            the result, so the caller
+    #                                            never reads them again
     step(state)             -> (state, outs)   # outs indexable by slot
     #                                            or None (state carries)
     finish(state, slot, first_out, collected) -> row value
@@ -359,11 +362,13 @@ class ContinuousEngine:
                                 "engine_insert", group,
                                 getattr(row.pending.req, "trace_id", None),
                                 slot=row.slot, step=k) as args:
+                            old = _leaves(self._state) if rec.enabled else ()
                             self._state = self.stepper.insert(
                                 self._state, row.slot, row_state)
                             if rec.enabled:
                                 _sync(self._state)
                                 args["bytes"] = _nbytes(row_state)
+                                args["donated"] = _all_deleted(old)
                         self.joins += 1
                     with self._lane_span("decode", group, step=k,
                                          n_live=len(live_now)):
@@ -522,11 +527,20 @@ def _sync(tree) -> None:
         jax.block_until_ready(tree)
 
 
-def _nbytes(tree) -> int:
+def _leaves(tree) -> list:
     jax = sys.modules.get("jax")
-    if jax is None:
-        return 0
-    return int(sum(getattr(x, "nbytes", 0) for x in jax.tree.leaves(tree)))
+    return [] if jax is None else jax.tree.leaves(tree)
+
+
+def _nbytes(tree) -> int:
+    return int(sum(getattr(x, "nbytes", 0) for x in _leaves(tree)))
+
+
+def _all_deleted(leaves) -> bool:
+    """Whether every array of ``leaves`` gave up its buffer, as the
+    arguments a call was donated do."""
+    arrays = [x for x in leaves if hasattr(x, "is_deleted")]
+    return bool(arrays) and all(x.is_deleted() for x in arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -583,6 +597,18 @@ class LMStepper:
 
         self._prefill = _prefill
 
+        def _insert(state, slot, row_cache, first):
+            return {"caches": self._cache_update(state["caches"], row_cache,
+                                                 slot),
+                    "tokens": state["tokens"].at[slot].set(
+                        first.astype(jnp.int32)),
+                    "pos": state["pos"].at[slot].set(self.prompt_len)}
+
+        # the slot state is donated: a join writes one row in place
+        # instead of copying every slot; ``slot`` is traced, so one
+        # compile serves every slot
+        self._insert = jax.jit(_insert, donate_argnums=0)
+
     def _lane_params(self):
         """``params`` on the device this call runs on, copied there once
         per device: prefill and decode may run on different lanes (the
@@ -600,12 +626,17 @@ class LMStepper:
 
     # -- protocol ----------------------------------------------------------
     def init_slots(self):
+        from repro.core.device import current_device
         jnp = self._jnp
         zeros = jnp.zeros((self.n_slots, self.prompt_len), jnp.int32)
         _, caches = self._prefill(self._lane_params(), zeros)
+        # committed to the lane's device, as every later state is (the
+        # caches already are, through the params): a state of the other
+        # kind would compile ``insert`` again at the first join after it
+        dev = current_device()
         return {"caches": caches,
-                "tokens": jnp.zeros((self.n_slots,), jnp.int32),
-                "pos": jnp.zeros((self.n_slots,), jnp.int32)}
+                "tokens": jnp.zeros((self.n_slots,), jnp.int32, device=dev),
+                "pos": jnp.zeros((self.n_slots,), jnp.int32, device=dev)}
 
     def prefill(self, spec):
         jax = self._jax
@@ -620,15 +651,12 @@ class LMStepper:
         return rows
 
     def insert(self, state, slot, row_state):
-        jax, jnp = self._jax, self._jnp
+        import numpy as np
+
         from repro.core.device import current_device
         # the row was prefilled on the prefill lane's device
-        row_cache, first = jax.device_put(row_state, current_device())
-        caches = self._cache_update(state["caches"], row_cache, slot)
-        return {"caches": caches,
-                "tokens": state["tokens"].at[slot].set(
-                    first.astype(jnp.int32)),
-                "pos": state["pos"].at[slot].set(self.prompt_len)}
+        row_cache, first = self._jax.device_put(row_state, current_device())
+        return self._insert(state, np.int32(slot), row_cache, first)
 
     def step(self, state):
         toks, caches = self._slot_step(self._lane_params(), state["tokens"],
@@ -636,7 +664,9 @@ class LMStepper:
         new = {"caches": caches, "tokens": toks,
                "pos": state["pos"] + 1}
         import numpy as np
-        return new, np.asarray(self._jax.device_get(toks))
+        # a copy: a host view of ``toks`` would keep the next insert
+        # from taking the tokens' buffer over
+        return new, np.array(self._jax.device_get(toks))
 
     def finish(self, state, slot, first_out, collected):
         import numpy as np
@@ -721,6 +751,12 @@ class IterStepper:
         self.prefill_cost = prefill_cost or CostTerms()
         self.decode_cost = decode_cost or CostTerms()
         self._step = jax.jit(jax.vmap(iter_fn))
+        # donated, as ``LMStepper``'s: a join writes its row in place
+        self._insert = jax.jit(
+            lambda state, slot, row: jax.tree.map(
+                lambda full, r: jax.lax.dynamic_update_index_in_dim(
+                    full, r, slot, 0), state, row),
+            donate_argnums=0)
 
     def init_slots(self):
         jax, jnp = self._jax, self._jax.numpy
@@ -733,11 +769,8 @@ class IterStepper:
                 for row_state, n_steps in self._make_rows(spec)]
 
     def insert(self, state, slot, row_state):
-        jax = self._jax
-        return jax.tree.map(
-            lambda full, r: jax.lax.dynamic_update_index_in_dim(
-                full, r, slot, 0),
-            state, row_state)
+        import numpy as np
+        return self._insert(state, np.int32(slot), row_state)
 
     def step(self, state):
         return self._step(state), None

@@ -419,6 +419,155 @@ def test_engine_lane_time_reaches_the_audit():
 
 
 # ---------------------------------------------------------------------------
+# joins write their row into the slot state in place
+# ---------------------------------------------------------------------------
+INSERT_SLOTS = 8
+
+
+@pytest.fixture(scope="module", params=["xlstm-350m",
+                                        "deepseek-v2-lite-16b"])
+def insert_stepper(request):
+    """A tiny LMStepper of 8 slots: xlstm-350m carries recurrent state
+    ("groups" leaves, batch axis 1), deepseek-v2-lite also a dense
+    prefix layer's KV cache ("prefix" leaves, batch axis 0)."""
+    import jax
+
+    from repro.serve.continuous import LMStepper
+
+    cfg = registry.get(request.param).reduced()
+    params = param.values(model_zoo.init(cfg, jax.random.key(0)))
+    return LMStepper(cfg, params, prompt_len=PROMPT_LEN,
+                     new_tokens=NEW_TOKENS, n_slots=INSERT_SLOTS)
+
+
+def _prompts(cfg, n, seed):
+    import jax
+    return jax.random.randint(jax.random.key(seed), (n, PROMPT_LEN), 0,
+                              cfg.vocab_size)
+
+
+def _row(stepper, seed):
+    """One prefilled row state, as the engine hands it to ``insert``."""
+    import types
+    spec = types.SimpleNamespace(arrays=(_prompts(stepper.cfg, 1, seed),))
+    ((row_state, _, _),) = stepper.prefill(spec)
+    return row_state
+
+
+def _slot_state(stepper):
+    """A slot state whose every slot holds a different row."""
+    import jax
+    import jax.numpy as jnp
+
+    first, caches = stepper._prefill(
+        stepper._lane_params(), _prompts(stepper.cfg, INSERT_SLOTS, 1))
+    pos = jnp.arange(INSERT_SLOTS, dtype=jnp.int32,
+                     device=jax.devices()[0])
+    return {"caches": caches, "tokens": first, "pos": pos}
+
+
+def _np_tree(tree):
+    import jax
+    return jax.tree.map(lambda a: np.array(a), tree)
+
+
+@pytest.mark.parametrize("slot", [0, 3, INSERT_SLOTS - 1])
+def test_lm_insert_writes_one_slot(insert_stepper, slot):
+    """``insert`` gives, bit for bit, the state of a plain NumPy write
+    of the row at ``slot`` (batch axis 1 of "groups" leaves, 0 of
+    "prefix" leaves, ``tokens`` and ``pos`` at the slot): the target
+    slot holds the row, every other slot is unchanged."""
+    import jax
+
+    st = insert_stepper
+    state = _slot_state(st)
+    row_cache, first = _row(st, 100 + slot)
+    want = _np_tree(state)
+    row = _np_tree(row_cache)
+    for full, r in zip(jax.tree.leaves(want["caches"]["groups"]),
+                       jax.tree.leaves(row["groups"])):
+        full[:, slot] = r
+    for full, r in zip(jax.tree.leaves(want["caches"].get("prefix", [])),
+                       jax.tree.leaves(row.get("prefix", []))):
+        full[slot] = r
+    want["tokens"][slot] = int(first)
+    want["pos"][slot] = PROMPT_LEN
+
+    got = _np_tree(st.insert(state, slot, (row_cache, first)))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+def test_lm_insert_donates_and_compiles_once(insert_stepper):
+    """``insert`` consumes the state it is given: every array of the old
+    state is deleted after the call, its buffers donated to the new
+    state; joins at every slot, from the fresh ``init_slots`` state on,
+    share one compiled call."""
+    import jax
+
+    st = insert_stepper
+    state = st.init_slots()
+    for slot in range(INSERT_SLOTS):
+        old = jax.tree.leaves(state)
+        state = st.insert(state, slot, _row(st, 200 + slot))
+        assert all(x.is_deleted() for x in old)
+        assert not any(x.is_deleted() for x in jax.tree.leaves(state))
+    state, _ = st.step(state)
+    st.insert(state, 0, _row(st, 300))
+    assert st._insert._cache_size() == 1
+
+
+def test_lm_engine_join_mid_decode_matches_solo(lm, live_recorder):
+    """Rows that join while another row is mid-decode write into the
+    running slot state in place; every request's tokens still equal its
+    solo ``generate``, and every ``engine_insert`` reads donated."""
+    from repro.serve.continuous import ContinuousEngine, LMStepper
+
+    cfg, params, wl = lm
+    stepper = LMStepper(cfg, params, prompt_len=PROMPT_LEN,
+                        new_tokens=NEW_TOKENS, n_slots=4)
+    specs = [adapters.make_request(wl, {"batch": 1, "seed": s})
+             for s in (11, 12, 13)]
+    reqs = [_Req(f"mid-{i}") for i in range(3)]
+    eng = None
+
+    def on_step(n_live):
+        # after the first row's first step, the other two arrive; the
+        # boundary waits for their prefill so both join mid-decode
+        if eng.steps != 1:
+            return
+        for r, sp in zip(reqs[1:], specs[1:]):
+            assert eng.submit(r, sp, time.monotonic())
+        with eng._cv:
+            assert eng._cv.wait_for(lambda: len(eng._ready) == 2,
+                                    timeout=120)
+
+    eng = ContinuousEngine(
+        stepper,
+        resolve=lambda req, value, t: req.future.set_result(value),
+        reject=lambda req, exc: req.future.set_exception(exc),
+        prefill_locks=[threading.Lock()], step_locks=[threading.Lock()],
+        prefill_group="accel", decode_group="host",
+        hooks={"on_step": on_step})
+    try:
+        assert eng.submit(reqs[0], specs[0], time.monotonic())
+        outs = [np.asarray(r.future.result(timeout=300)) for r in reqs]
+    finally:
+        eng.shutdown()
+    for sp, out in zip(specs, outs):
+        np.testing.assert_array_equal(out, _solo(cfg, params, sp.arrays[0]))
+    snap = eng.snapshot()
+    assert snap["joins"] == 3 and snap["max_live"] == 3
+    inserts = [e for e in live_recorder.events()
+               if e["name"] == "engine_insert"]
+    assert len(inserts) == 3
+    assert [e["args"]["step"] for e in inserts] == [0, 1, 1]
+    assert all(e["args"]["donated"] is True for e in inserts)
+
+
+# ---------------------------------------------------------------------------
 # satellite: hist / conv merge hooks (array-level batching)
 # ---------------------------------------------------------------------------
 def test_hist_merge_demux_bit_identical():

@@ -33,6 +33,8 @@ class MoEConfig:
     # explicitly constrain dispatch buffers to (batch, expert) sharding
     # (§Perf optimization: stops XLA from resharding through permutes)
     shard_dispatch: bool = False
+    # gates renormalised over the top-k (DeepSeek-V2: norm_topk_prob)
+    norm_topk_prob: bool = True
     # expert-weight sharding (§Perf): "ep" shards the expert axis over
     # the model mesh axis (baseline; dispatch scatter/gather cross-shard)
     # or "tp" shards the per-expert FFN dim instead (expert slicing:
@@ -47,6 +49,23 @@ class MLAConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071) as DeepSeek-V2 defines it:
+    frequency pairs that turn fewer than ``beta_slow`` times over
+    ``original_max_position_embeddings`` are divided by ``factor``, those
+    that turn more than ``beta_fast`` times are kept, a linear ramp lies
+    between; the softmax scale gains ``mscale(factor, mscale_all_dim)**2``
+    and cos/sin ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -103,6 +122,7 @@ class ArchConfig:
     sliding_window: int = 0          # 0 => full attention
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     logit_softcap: float = 0.0
 
     # --- block layout ---

@@ -1,14 +1,17 @@
 """deepseek-v2-lite-16b [moe] — MLA kv_lora=512, 2 shared + 64 routed
-top-6 [arXiv:2405.04434].
+top-6 [arXiv:2405.04434; hf:deepseek-ai/DeepSeek-V2-Lite config.json].
 
 27L d_model=2048 16H vocab=102400; expert d_ff=1408; first layer dense
-(d_ff=10944). Full attention => long_500k SKIPPED.
+(d_ff=10944); RMS norm eps 1e-6; softmax router, greedy top-6, gates not
+renormalised (norm_topk_prob false, routed_scaling_factor 1); YaRN rope
+(factor 40 over 4096 positions). Full attention => long_500k SKIPPED.
 
 Config note (DESIGN.md §5): the assignment's primary spec says
 "MoE 64e top-6" while its descriptor mentions 160 routed; we follow the
 primary spec (64 routed), which matches the public HF config.
 """
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, ParallelConfig
+from repro.configs.base import (ArchConfig, MLAConfig, MoEConfig,
+                                ParallelConfig, YarnConfig)
 
 CONFIG = ArchConfig(
     name="deepseek-v2-lite-16b",
@@ -24,7 +27,13 @@ CONFIG = ArchConfig(
     mla=MLAConfig(kv_lora_rank=512, q_lora_rank=0, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(n_routed=64, n_shared=2, top_k=6, d_ff=1408,
-                  n_dense_layers=1, capacity_factor=1.25),
+                  n_dense_layers=1, capacity_factor=1.25,
+                  norm_topk_prob=False),
+    rope_scaling=YarnConfig(factor=40.0,
+                            original_max_position_embeddings=4096,
+                            beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                            mscale_all_dim=0.707),
+    norm_eps=1e-6,
     max_seq_len=131072,
     supports_long_context=False,
     parallel=ParallelConfig(fsdp=False, remat="dots"),

@@ -84,7 +84,9 @@ def init_layer_cache(cfg, kind: str, batch: int, max_len: int,
 
 def apply_layer(params, x, cfg, kind: str, use_moe: bool, *, sin, cos,
                 kv_repeat: int = 1, make_cache_len: int = 0):
-    """Full-sequence layer. Returns (x, cache, aux_loss)."""
+    """Full-sequence layer. Returns (x, cache, aux_loss, route).  With
+    ``make_cache_len`` (a serving prefill) an expert layer drops no
+    assignment and ``route`` is its (B, E) expert counts; else None."""
     h = norm(params["norm1"], x, cfg)
     cache = None
     if kind == "attn":
@@ -111,21 +113,25 @@ def apply_layer(params, x, cfg, kind: str, use_moe: bool, *, sin, cos,
     x = shard_act(x, ("batch", seq_ax, "embed"))
     x = jax.ad_checkpoint.checkpoint_name(x, "blk_attn_out")
     aux = jnp.zeros((), jnp.float32)
+    route = None
     if "ffn" in params:
         h = norm(params["norm2"], x, cfg)
-        if use_moe:
+        if use_moe and make_cache_len:
+            y, route = moe_mod.moe_serve(params["ffn"], h, cfg)
+        elif use_moe:
             y, aux = moe_mod.moe_ffn(params["ffn"], h, cfg)
         else:
             y = mlp(params["ffn"], h, cfg)
         x = x + y
         x = shard_act(x, ("batch", seq_ax, "embed"))
         x = jax.ad_checkpoint.checkpoint_name(x, "blk_ffn_out")
-    return x, cache, aux
+    return x, cache, aux, route
 
 
 def apply_layer_decode(params, x, cfg, kind: str, use_moe: bool, cache,
                        position, *, sin, cos, kv_repeat: int = 1):
-    """Single-token layer step. Returns (x, new_cache, aux)."""
+    """Single-token layer step. Returns (x, new_cache, route): an expert
+    layer's (B, E) expert counts, else None."""
     h = norm(params["norm1"], x, cfg)
     if kind == "attn":
         y, cache = attn_mod.attention_decode(
@@ -144,15 +150,15 @@ def apply_layer_decode(params, x, cfg, kind: str, use_moe: bool, cache,
     else:
         raise ValueError(kind)
     x = x + y
-    aux = jnp.zeros((), jnp.float32)
+    route = None
     if "ffn" in params:
         h = norm(params["norm2"], x, cfg)
         if use_moe:
-            y, aux = moe_mod.moe_ffn(params["ffn"], h, cfg)
+            y, route = moe_mod.moe_serve(params["ffn"], h, cfg)
         else:
             y = mlp(params["ffn"], h, cfg)
         x = x + y
-    return x, cache, aux
+    return x, cache, route
 
 
 # ---------------------------------------------------------------------------
@@ -174,28 +180,34 @@ def init_group_cache(cfg, batch: int, max_len: int, kv_repeat: int = 1,
 
 
 def apply_group(params, x, cfg, *, sin, cos, kv_repeat=1, make_cache_len=0):
+    """Returns (x, caches, aux, routes): ``routes`` maps each expert
+    layer of the group to its counts on the serving path, else None."""
     kinds, moe_flags, _ = group_layout(cfg)
-    caches, aux = {}, jnp.zeros((), jnp.float32)
+    caches, aux, routes = {}, jnp.zeros((), jnp.float32), {}
     for i, (kind, mf) in enumerate(zip(kinds, moe_flags)):
-        x, c, a = apply_layer(params[f"l{i}"], x, cfg, kind, mf, sin=sin,
-                              cos=cos, kv_repeat=kv_repeat,
-                              make_cache_len=make_cache_len)
+        x, c, a, r = apply_layer(params[f"l{i}"], x, cfg, kind, mf, sin=sin,
+                                 cos=cos, kv_repeat=kv_repeat,
+                                 make_cache_len=make_cache_len)
         caches[f"l{i}"] = c
         aux = aux + a
-    return x, (caches if make_cache_len else None), aux
+        if r is not None:
+            routes[f"l{i}"] = r
+    return x, (caches if make_cache_len else None), aux, routes or None
 
 
 def apply_group_decode(params, x, cfg, caches, position, *, sin, cos,
                        kv_repeat=1):
+    """Returns (x, new_caches, routes), as ``apply_group``."""
     kinds, moe_flags, _ = group_layout(cfg)
-    new_caches, aux = {}, jnp.zeros((), jnp.float32)
+    new_caches, routes = {}, {}
     for i, (kind, mf) in enumerate(zip(kinds, moe_flags)):
-        x, c, a = apply_layer_decode(params[f"l{i}"], x, cfg, kind, mf,
+        x, c, r = apply_layer_decode(params[f"l{i}"], x, cfg, kind, mf,
                                      caches[f"l{i}"], position, sin=sin,
                                      cos=cos, kv_repeat=kv_repeat)
         new_caches[f"l{i}"] = c
-        aux = aux + a
-    return x, new_caches, aux
+        if r is not None:
+            routes[f"l{i}"] = r
+    return x, new_caches, routes or None
 
 
 def _remat_wrap(fn, cfg):
@@ -260,27 +272,30 @@ def init_stack_caches(cfg, batch: int, max_len: int, kv_repeat: int = 1,
 
 
 def apply_stack(params, x, cfg, *, sin, cos, kv_repeat=1, make_cache_len=0):
-    """Returns (x, caches, aux)."""
+    """Returns (x, caches, aux, route).  With ``make_cache_len``, an
+    expert model's ``route`` holds the call's expert counts: per expert
+    layer of the group, (n_groups, B, E); else None."""
     kinds0 = ("mla" if cfg.attn_type == "mla" else "attn")
     prefix_caches = []
     aux = jnp.zeros((), jnp.float32)
     for lp in params.get("prefix", []):
-        x, c, a = apply_layer(lp, x, cfg, kinds0, False, sin=sin, cos=cos,
-                              kv_repeat=kv_repeat,
-                              make_cache_len=make_cache_len)
+        x, c, a, _ = apply_layer(lp, x, cfg, kinds0, False, sin=sin,
+                                 cos=cos, kv_repeat=kv_repeat,
+                                 make_cache_len=make_cache_len)
         prefix_caches.append(c)
         aux = aux + a
 
     def body(carry, gparams):
         x, aux = carry
-        x, cache, a = apply_group(gparams, x, cfg, sin=sin, cos=cos,
-                                  kv_repeat=kv_repeat,
-                                  make_cache_len=make_cache_len)
-        return (x, aux + a), cache
+        x, cache, a, route = apply_group(gparams, x, cfg, sin=sin, cos=cos,
+                                         kv_repeat=kv_repeat,
+                                         make_cache_len=make_cache_len)
+        return (x, aux + a), (cache, route)
 
     body = _remat_wrap(body, cfg)
     if cfg.parallel.scan_layers:
-        (x, aux), gcaches = jax.lax.scan(body, (x, aux), params["groups"])
+        (x, aux), (gcaches, routes) = jax.lax.scan(body, (x, aux),
+                                                   params["groups"])
     else:
         # unrolled python loop (probe mode: makes every layer's FLOPs
         # visible to XLA cost analysis, which counts scan bodies once)
@@ -290,47 +305,46 @@ def apply_stack(params, x, cfg, *, sin, cos, kv_repeat=1, make_cache_len=0):
             gp = jax.tree.map(lambda a: a[i], params["groups"])
             (x, aux), c = body((x, aux), gp)
             cl.append(c)
-        gcaches = (jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
-                   if make_cache_len else None)
+        gcaches, routes = (jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
+                           if make_cache_len else (None, None))
     caches = None
     if make_cache_len:
         caches = {"groups": gcaches}
         if prefix_caches:
             caches["prefix"] = prefix_caches
-    return x, caches, aux
+    return x, caches, aux, routes
 
 
 def apply_stack_decode(params, x, cfg, caches, position, *, sin, cos,
                        kv_repeat=1):
+    """Returns (x, new_caches, route), ``route`` as in ``apply_stack``."""
     kinds0 = ("mla" if cfg.attn_type == "mla" else "attn")
-    aux = jnp.zeros((), jnp.float32)
     new_prefix = []
     for lp, c in zip(params.get("prefix", []), caches.get("prefix", [])):
-        x, c2, a = apply_layer_decode(lp, x, cfg, kinds0, False, c, position,
+        x, c2, _ = apply_layer_decode(lp, x, cfg, kinds0, False, c, position,
                                       sin=sin, cos=cos, kv_repeat=kv_repeat)
         new_prefix.append(c2)
-        aux = aux + a
 
-    def body(carry, xs):
-        x, aux = carry
+    def body(x, xs):
         gparams, gcache = xs
-        x, c2, a = apply_group_decode(gparams, x, cfg, gcache, position,
-                                      sin=sin, cos=cos, kv_repeat=kv_repeat)
-        return (x, aux + a), c2
+        x, c2, route = apply_group_decode(gparams, x, cfg, gcache, position,
+                                          sin=sin, cos=cos,
+                                          kv_repeat=kv_repeat)
+        return x, (c2, route)
 
     if cfg.parallel.scan_layers:
-        (x, aux), gcaches = jax.lax.scan(
-            body, (x, aux), (params["groups"], caches["groups"]))
+        x, (gcaches, routes) = jax.lax.scan(
+            body, x, (params["groups"], caches["groups"]))
     else:
         _, _, n_groups = group_layout(cfg)
         cl = []
         for i in range(n_groups):
             xs_i = jax.tree.map(lambda a: a[i],
                                 (params["groups"], caches["groups"]))
-            (x, aux), c = body((x, aux), xs_i)
+            x, c = body(x, xs_i)
             cl.append(c)
-        gcaches = jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
+        gcaches, routes = jax.tree.map(lambda *xs: jnp.stack(xs), *cl)
     new_caches = {"groups": gcaches}
     if new_prefix:
         new_caches["prefix"] = new_prefix
-    return x, new_caches, aux
+    return x, new_caches, routes

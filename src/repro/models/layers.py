@@ -1,6 +1,7 @@
 """Core layers: norms, linear, MLP/GLU, embeddings, RoPE."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -129,19 +130,63 @@ def unembed(params, x, cfg, embed_params=None):
 # RoPE
 # ---------------------------------------------------------------------------
 def rope_table(dim: int, max_len: int, theta: float = 10000.0,
-               positions: Optional[jnp.ndarray] = None):
+               positions: Optional[jnp.ndarray] = None, scaling=None):
     """Paper §4.6 'LUT on the host' analogue: the sin/cos table is a pure
     function of (dim, theta) and is precomputed once (host task) rather
-    than re-evaluated per step (see core.host_offload)."""
+    than re-evaluated per step (see core.host_offload).  ``scaling``
+    (a ``YarnConfig``) gives YaRN's frequencies and amplitude."""
     if positions is None:
         positions = jnp.arange(max_len)
     freqs = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    amp = 1.0
+    if scaling is not None:
+        freqs = freqs * yarn_freq_factor(dim, theta, scaling)
+        amp = (yarn_mscale(scaling.factor, scaling.mscale)
+               / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., L, dim/2)
-    return jnp.sin(ang), jnp.cos(ang)
+    return jnp.sin(ang) * amp, jnp.cos(ang) * amp
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term ``0.1 * mscale * ln(factor) + 1``
+    (1 where the positions are not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freq_factor(dim: int, theta: float, y) -> jnp.ndarray:
+    """(dim/2,) multipliers of the plain rotary frequencies: 1 for the
+    pairs that turn more than ``beta_fast`` times over the original
+    context, ``1/factor`` for those that turn fewer than ``beta_slow``
+    times, a linear ramp in pair index between (DeepSeek-V2's rounding of
+    the ramp's ends)."""
+    def pair(n_rot):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (n_rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(pair(y.beta_fast)), 0)
+    hi = min(math.ceil(pair(y.beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (1.0 - ramp) + ramp / y.factor
+
+
+def softmax_scale(cfg, head_dim: int) -> float:
+    """``head_dim ** -0.5``, times YaRN's ``mscale(factor,
+    mscale_all_dim) ** 2`` where the config scales its rotary positions
+    (DeepSeek-V2)."""
+    scale = head_dim ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
 
 
 def apply_rope(x, sin, cos):
-    """x: (..., L, H, dh); sin/cos: (L, dh/2) or broadcastable."""
+    """x: (..., L, H, dh); sin/cos: (L, dh/2) or broadcastable.  Pair i
+    is (x[i], x[i + dh/2]): the half-split layout.  A checkpoint that
+    pairs adjacent dims (DeepSeek-V2's) maps to it by de-interleaving the
+    rope columns of its q and k projections."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
     x1, x2 = jnp.split(x, 2, axis=-1)

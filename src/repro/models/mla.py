@@ -10,9 +10,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, init_linear, linear
+from repro.models.layers import (apply_rope, init_linear, linear,
+                                 rms_norm_simple, softmax_scale)
 from repro.models.param import ones_init
-from repro.models.layers import rms_norm_simple
 from repro.parallel.sharding import shard_act
 
 
@@ -67,6 +67,7 @@ def _latent_kv(params, x, cfg, sin, cos):
     return c_kv, k_rope
 
 
+@jax.named_scope("mla")
 def mla_attention(params, x, cfg, *, sin=None, cos=None,
                   make_cache_len: int = 0, kv_repeat: int = 1):
     """Naive (expanded) MLA for train/prefill. Returns (y, cache)."""
@@ -78,7 +79,7 @@ def mla_attention(params, x, cfg, *, sin=None, cos=None,
     kv = linear(params["wkv_b"], c_kv).reshape(B, T, H, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
 
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg, dn + dr)
     s = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope,
@@ -102,6 +103,7 @@ def init_mla_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
             "kr": jnp.zeros((batch, max_len, dr), dtype)}
 
 
+@jax.named_scope("mla")
 def mla_decode(params, x, cfg, cache, position, *, sin=None, cos=None,
                kv_repeat: int = 1):
     """Absorbed-form single-token decode against the latent cache."""
@@ -118,7 +120,7 @@ def mla_decode(params, x, cfg, cache, position, *, sin=None, cos=None,
     wk, wv = wkv_b[..., :dn], wkv_b[..., dn:]
     # absorb: q_lat[b,h,l] = sum_d q_nope[b,h,d] * wk[l,h,d]
     q_lat = jnp.einsum("bthd,lhd->bthl", q_nope, wk)
-    scale = (dn + dr) ** -0.5
+    scale = softmax_scale(cfg, dn + dr)
     s = (jnp.einsum("bthl,bsl->bhts", q_lat, ckv,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bthd,bsd->bhts", q_rope, kr,

@@ -33,12 +33,16 @@ def forward(cfg: ArchConfig, params, batch: Dict[str, jnp.ndarray],
                                             batch["dec_tokens"], cfg, tp=tp)
         return logits, jnp.zeros((), jnp.float32)
     inputs = batch.get("embeds", batch.get("tokens"))
-    logits, _, aux = tf_mod.lm_forward(params, inputs, cfg, tp=tp)
+    logits, _, aux, _ = tf_mod.lm_forward(params, inputs, cfg, tp=tp)
     return logits, aux
 
 
-def prefill(cfg: ArchConfig, params, batch, cache_len: int, *, tp: int = 1):
-    """Prefill pass that also materializes decode caches."""
+def prefill(cfg: ArchConfig, params, batch, cache_len: int, *, tp: int = 1,
+            with_route: bool = False):
+    """Prefill pass that also materializes decode caches.  Returns
+    (logits, caches), and with ``with_route`` the expert counts after
+    them (``transformer.lm_forward``; None for a model without
+    experts)."""
     if cfg.is_encoder_decoder:
         enc_out = encdec_mod.encode(params, batch["frames"], cfg, tp=tp)
         logits, _ = encdec_mod.decode_train(params, enc_out,
@@ -46,11 +50,11 @@ def prefill(cfg: ArchConfig, params, batch, cache_len: int, *, tp: int = 1):
         caches = encdec_mod.init_dec_caches(
             params, enc_out, cfg, batch["dec_tokens"].shape[0], cache_len,
             tp=tp)
-        return logits, caches
+        return (logits, caches, None) if with_route else (logits, caches)
     inputs = batch.get("embeds", batch.get("tokens"))
-    logits, caches, _ = tf_mod.lm_forward(params, inputs, cfg, tp=tp,
-                                          make_cache_len=cache_len)
-    return logits, caches
+    logits, caches, _, route = tf_mod.lm_forward(
+        params, inputs, cfg, tp=tp, make_cache_len=cache_len)
+    return (logits, caches, route) if with_route else (logits, caches)
 
 
 def init_caches(cfg: ArchConfig, batch: int, max_len: int, *, tp: int = 1,
@@ -63,12 +67,15 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int, *, tp: int = 1,
 
 
 def decode_step(cfg: ArchConfig, params, token, caches, position,
-                *, tp: int = 1):
-    """One-token decode. Returns (logits, new_caches)."""
+                *, tp: int = 1, with_route: bool = False):
+    """One-token decode. Returns (logits, new_caches), and with
+    ``with_route`` the expert counts after them, as ``prefill``."""
     if cfg.is_encoder_decoder:
-        return encdec_mod.decode_step(params, token, cfg, caches, position,
-                                      tp=tp)
-    return tf_mod.lm_decode_step(params, token, cfg, caches, position, tp=tp)
+        out = encdec_mod.decode_step(params, token, cfg, caches, position,
+                                     tp=tp)
+        return out + (None,) if with_route else out
+    out = tf_mod.lm_decode_step(params, token, cfg, caches, position, tp=tp)
+    return out if with_route else out[:2]
 
 
 # ---------------------------------------------------------------------------

@@ -26,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 from jax.experimental.shard_map import shard_map
 
 from repro.models.layers import ACTS
-from repro.models.moe import _dispatch_indices, _dispatch_onehot
+from repro.models.moe import _dispatch_indices, _dispatch_onehot, route
 from repro.parallel.sharding import active_mesh
 
 
@@ -40,11 +40,7 @@ def _local_moe(params, x_loc, cfg, data_ax: str, model_ax: str,
     act = ACTS[cfg.act]
     C = max(1, int(T * k / E * m.capacity_factor))
 
-    logits = (x_loc @ params["router"]["w"].astype(x_loc.dtype)
-              ).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, topk_idx = jax.lax.top_k(probs, k)
-    gate = gate / jnp.clip(jnp.sum(gate, -1, keepdims=True), 1e-9)
+    probs, gate, topk_idx = route(params, x_loc, cfg)
 
     # aux loss over the full batch: local means + pmean over data
     me = jax.lax.pmean(jnp.mean(probs, axis=(0, 1)), data_ax)

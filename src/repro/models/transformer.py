@@ -47,20 +47,24 @@ def lm_forward(params, inputs, cfg, *, tp: int = 1, make_cache_len: int = 0,
                positions: Optional[jnp.ndarray] = None):
     """inputs: (B, T) int tokens or (B, T, d) stub embeddings.
 
-    Returns (logits, caches, aux_loss)."""
+    Returns (logits, caches, aux_loss, route).  With ``make_cache_len``
+    (a serving prefill) expert layers drop nothing, and an expert
+    model's ``route`` counts each row's assignments per expert
+    (``blocks.apply_stack``); else it is None."""
     x = _inputs_to_h(params, inputs, cfg).astype(jnp.bfloat16)
     x = shard_act(x, ("batch", None, "embed"))
     sin = cos = None
     if _has_attn(cfg):
         T = x.shape[1]
         pos = positions if positions is not None else jnp.arange(T)
-        sin, cos = rope_table(_rope_dim(cfg), T, cfg.rope_theta, pos)
+        sin, cos = rope_table(_rope_dim(cfg), T, cfg.rope_theta, pos,
+                              cfg.rope_scaling)
     kv_rep = attn_mod.kv_repeat_for(cfg, tp)
-    x, caches, aux = blocks.apply_stack(
+    x, caches, aux, route = blocks.apply_stack(
         params["stack"], x, cfg, sin=sin, cos=cos, kv_repeat=kv_rep,
         make_cache_len=make_cache_len)
     logits = shard_act(_head(params, x, cfg), ("batch", None, "vocab"))
-    return logits, caches, aux
+    return logits, caches, aux, route
 
 
 def init_lm_caches(cfg, batch: int, max_len: int, tp: int = 1,
@@ -72,17 +76,19 @@ def init_lm_caches(cfg, batch: int, max_len: int, tp: int = 1,
 def lm_decode_step(params, inputs, cfg, caches, position, *, tp: int = 1):
     """inputs: (B, 1) token ids (or (B, 1, d) embeds); position: scalar.
 
-    Returns (logits (B, 1, V), new_caches)."""
+    Returns (logits (B, 1, V), new_caches, route), ``route`` as in
+    ``lm_forward``."""
     x = _inputs_to_h(params, inputs, cfg).astype(jnp.bfloat16)
     sin = cos = None
     if _has_attn(cfg):
         pos = jnp.asarray(position)[None]
-        sin, cos = rope_table(_rope_dim(cfg), 1, cfg.rope_theta, pos)
+        sin, cos = rope_table(_rope_dim(cfg), 1, cfg.rope_theta, pos,
+                              cfg.rope_scaling)
     kv_rep = attn_mod.kv_repeat_for(cfg, tp)
-    x, new_caches, _ = blocks.apply_stack_decode(
+    x, new_caches, route = blocks.apply_stack_decode(
         params["stack"], x, cfg, caches, position, sin=sin, cos=cos,
         kv_repeat=kv_rep)
-    return _head(params, x, cfg), new_caches
+    return _head(params, x, cfg), new_caches, route
 
 
 def _head(params, x, cfg):

@@ -48,6 +48,13 @@ Steppers are duck-typed; the engine needs::
     #                                            or None (state carries)
     finish(state, slot, first_out, collected) -> row value
     assemble(row_values)    -> request value (solo-identical order)
+
+and may add, for the recorder's spans::
+
+    route_args(phase, slots) -> {arg: value}  # the routing of the last
+    #   ``prefill`` (phase "prefill") or ``step`` ("decode"; ``slots``
+    #   the live ones); read inside that call's span, on the thread and
+    #   under the lane locks that made the call.  Without it: no args.
 """
 from __future__ import annotations
 
@@ -125,9 +132,12 @@ class ContinuousEngine:
     joined row's ``engine_insert`` and the ``decode`` call, on the
     ``lane:<group>`` track of the lane that ran them.  Every child of
     step ``k`` carries ``step=k``; ``engine_boundary`` covers the host
-    work between two steps.  While the recorder is on, ``init_slots``,
-    each insert and each step wait for their outputs before their spans
-    close, so the spans time the work and not its dispatch.
+    work between two steps.  For a model with routed experts,
+    ``prefill_call`` also carries ``expert_load_max`` and ``decode``
+    ``experts_hit`` (``LMStepper.route_args``).  While the recorder is
+    on, ``init_slots``, each insert and each step wait for their outputs
+    before their spans close, so the spans time the work and not its
+    dispatch.
 
     ``should_yield()`` (optional) is polled at every step boundary:
     while it returns True — the Scheduler dispatched latency-class
@@ -251,6 +261,8 @@ class ContinuousEngine:
                                 trace_id) as args:
                             rows = self.stepper.prefill(pending.spec)
                             args["rows"] = len(rows)
+                            if rec.enabled:
+                                args.update(self._route_args("prefill"))
                     finally:
                         for lk in reversed(self.prefill_locks):
                             lk.release()
@@ -371,15 +383,23 @@ class ContinuousEngine:
                                 args["donated"] = _all_deleted(old)
                         self.joins += 1
                     with self._lane_span("decode", group, step=k,
-                                         n_live=len(live_now)):
+                                         n_live=len(live_now)) as args:
                         self._state, outs = self.stepper.step(self._state)
                         if rec.enabled:
                             _sync(self._state)
+                            args.update(self._route_args("decode",
+                                                         sorted(live_now)))
                 self.steps += 1
             finally:
                 for lk in reversed(self.step_locks):
                     lk.release()
         return outs
+
+    def _route_args(self, phase: str, slots=()) -> Dict[str, float]:
+        """The stepper's optional ``route_args`` for a ``phase`` span;
+        {} for a stepper without it."""
+        fn = getattr(self.stepper, "route_args", None)
+        return fn(phase, slots) if fn is not None else {}
 
     def _retire(self, live_now: Dict[int, _Row], outs) -> int:
         """Collect a step's outputs; evict and finish the rows that are
@@ -590,12 +610,17 @@ class LMStepper:
         @jax.jit
         @jax.named_scope("prefill")
         def _prefill(params, prompt):
-            logits, caches = model_zoo.prefill(
-                cfg, params, {"tokens": prompt}, cache_len=L, tp=tp_)
+            logits, caches, route = model_zoo.prefill(
+                cfg, params, {"tokens": prompt}, cache_len=L, tp=tp_,
+                with_route=True)
             first = jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1)
-            return first.astype(jnp.int32), caches
+            return first.astype(jnp.int32), caches, route
 
         self._prefill = _prefill
+        # the expert counts of the last prefill and of the last step
+        # (None for a model without experts), for ``route_args``: each
+        # phase is called and read by one engine thread
+        self._route = {"prefill": None, "decode": None}
 
         def _insert(state, slot, row_cache, first):
             return {"caches": self._cache_update(state["caches"], row_cache,
@@ -626,22 +651,32 @@ class LMStepper:
 
     # -- protocol ----------------------------------------------------------
     def init_slots(self):
+        """The empty slot state, built from the shapes of a size-S prefill
+        and filled with zeros: every slot is overwritten by its first
+        join, so nothing is computed."""
         from repro.core.device import current_device
-        jnp = self._jnp
-        zeros = jnp.zeros((self.n_slots, self.prompt_len), jnp.int32)
-        _, caches = self._prefill(self._lane_params(), zeros)
-        # committed to the lane's device, as every later state is (the
-        # caches already are, through the params): a state of the other
-        # kind would compile ``insert`` again at the first join after it
+        jax, jnp = self._jax, self._jnp
+        prompt = jax.ShapeDtypeStruct((self.n_slots, self.prompt_len),
+                                      jnp.int32)
+        _, caches, _ = jax.eval_shape(self._prefill, self.params, prompt)
+        # committed to the lane's device, as every later state is: a
+        # state of the other kind would compile ``insert`` again at the
+        # first join after it
         dev = current_device()
-        return {"caches": caches,
-                "tokens": jnp.zeros((self.n_slots,), jnp.int32, device=dev),
-                "pos": jnp.zeros((self.n_slots,), jnp.int32, device=dev)}
+
+        def zeros(shape, dtype):
+            return jnp.zeros(shape, dtype, device=dev)
+
+        return {"caches": jax.tree.map(lambda a: zeros(a.shape, a.dtype),
+                                       caches),
+                "tokens": zeros((self.n_slots,), jnp.int32),
+                "pos": zeros((self.n_slots,), jnp.int32)}
 
     def prefill(self, spec):
         jax = self._jax
         prompt = self._jnp.asarray(spec.arrays[0])
-        first, caches = self._prefill(self._lane_params(), prompt)
+        first, caches, self._route["prefill"] = self._prefill(
+            self._lane_params(), prompt)
         first_host = [int(t) for t in jax.device_get(first)]
         rows = []
         for b in range(prompt.shape[0]):
@@ -659,8 +694,9 @@ class LMStepper:
         return self._insert(state, np.int32(slot), row_cache, first)
 
     def step(self, state):
-        toks, caches = self._slot_step(self._lane_params(), state["tokens"],
-                                       state["caches"], state["pos"])
+        toks, caches, self._route["decode"] = self._slot_step(
+            self._lane_params(), state["tokens"], state["caches"],
+            state["pos"])
         new = {"caches": caches, "tokens": toks,
                "pos": state["pos"] + 1}
         import numpy as np
@@ -673,6 +709,28 @@ class LMStepper:
         return np.asarray([first_out] + [int(t) for t in collected],
                           dtype=np.int32)[None, :]
 
+    def route_args(self, phase: str, slots=()) -> Dict[str, float]:
+        """Span args of the routing of the last ``phase`` call; {} for a
+        model without experts.  ``"prefill"``: ``expert_load_max``, the
+        busiest routed expert's assignments over the mean per expert,
+        the largest over the expert layers.  ``"decode"``:
+        ``experts_hit``, the distinct routed experts that the live
+        ``slots`` chose, the mean over the expert layers."""
+        import numpy as np
+        route = self._route[phase]
+        if route is None:
+            return {}
+        leaves = [np.asarray(a) for a in self._jax.tree.leaves(route)]
+        if phase == "prefill":
+            # (n_layers, rows, E): the call's load per expert and layer
+            load = np.concatenate(leaves, axis=0).sum(axis=1)
+            mean = load.sum(axis=1) / load.shape[1]
+            return {"expert_load_max":
+                    float((load.max(axis=1) / mean).max())}
+        # (S, n_layers, E): the experts any live slot chose, per layer
+        hit = np.concatenate(leaves, axis=1)[list(slots)] > 0
+        return {"experts_hit": float(hit.any(axis=0).sum(axis=1).mean())}
+
     def assemble(self, row_values):
         import numpy as np
         return np.concatenate(row_values, axis=0)
@@ -683,7 +741,7 @@ class LMStepper:
         jnp = self._jnp
         state = self.init_slots()
         for b in batch_sizes:
-            first, caches = self._prefill(
+            first, caches, _ = self._prefill(
                 self._lane_params(),
                 jnp.zeros((int(b), self.prompt_len), jnp.int32))
             row = self._slice_cache(caches, 0)

@@ -30,19 +30,23 @@ def batch_len(batch: Dict) -> int:
 
 
 def make_serve_step(cfg: ArchConfig, *, tp: int = 1,
-                    temperature: float = 0.0):
+                    temperature: float = 0.0, with_route: bool = False):
     """serve_step(params, token, caches, position[, key]) ->
-    (next_token, new_caches)."""
+    (next_token, new_caches), and with ``with_route`` the expert counts
+    after them (``model_zoo.decode_step``)."""
 
     def serve_step(params, token, caches, position, key=None):
-        logits, new_caches = model_zoo.decode_step(
-            cfg, params, token, caches, position, tp=tp)
+        logits, new_caches, route = model_zoo.decode_step(
+            cfg, params, token, caches, position, tp=tp, with_route=True)
         logits = logits[:, 0].astype(jnp.float32)
         if temperature > 0.0 and key is not None:
             next_tok = jax.random.categorical(key, logits / temperature)
         else:
             next_tok = jnp.argmax(logits, axis=-1)
-        return next_tok[:, None].astype(jnp.int32), new_caches
+        next_tok = next_tok[:, None].astype(jnp.int32)
+        if with_route:
+            return next_tok, new_caches, route
+        return next_tok, new_caches
 
     return serve_step
 
@@ -51,7 +55,7 @@ def make_slot_step(cfg: ArchConfig, *, tp: int = 1):
     """Slot-batched decode step for the continuous-batching engine.
 
     ``slot_step(params, tokens, slot_caches, positions) ->
-    (next_tokens, new_slot_caches)`` where every array carries a leading
+    (next_tokens, new_slot_caches, route)`` where every array carries a leading
     *slot* axis of fixed size S: ``tokens``/``positions`` are ``(S,)``
     int32 and ``slot_caches`` is a per-row cache pytree stacked on a new
     slot axis.  Built as ``vmap`` of the single-request ``serve_step``
@@ -64,8 +68,11 @@ def make_slot_step(cfg: ArchConfig, *, tp: int = 1):
     the optional ``"prefix"`` per-layer caches carry batch at 0 — the
     in/out axes pytree below maps each accordingly.  Per-slot positions
     let rows sit at different decode depths inside one kernel call.
+    ``route`` is each slot's expert counts per expert layer, (S,
+    n_groups, E) leaves (``lm_decode_step``), or None for a model
+    without experts.
     """
-    step = make_serve_step(cfg, tp=tp)
+    step = make_serve_step(cfg, tp=tp, with_route=True)
 
     def _add_b(caches):
         out = {"groups": jax.tree.map(lambda a: a[:, None],
@@ -84,8 +91,10 @@ def make_slot_step(cfg: ArchConfig, *, tp: int = 1):
         return out
 
     def _row(params, tok, cache_row, pos):
-        nxt, new = step(params, tok[None, None], _add_b(cache_row), pos)
-        return nxt[0, 0], _drop_b(new)
+        nxt, new, route = step(params, tok[None, None], _add_b(cache_row),
+                               pos)
+        route = jax.tree.map(lambda a: a[:, 0], route)
+        return nxt[0, 0], _drop_b(new), route
 
     def _axes(caches):
         axes = {"groups": 1}
@@ -98,8 +107,8 @@ def make_slot_step(cfg: ArchConfig, *, tp: int = 1):
     def slot_step(params, tokens, slot_caches, positions):
         axes = _axes(slot_caches)
         return jax.vmap(_row, in_axes=(None, 0, axes, 0),
-                        out_axes=(0, axes))(params, tokens, slot_caches,
-                                            positions)
+                        out_axes=(0, axes, 0))(params, tokens, slot_caches,
+                                               positions)
 
     return slot_step
 

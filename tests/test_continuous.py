@@ -459,7 +459,7 @@ def _slot_state(stepper):
     import jax
     import jax.numpy as jnp
 
-    first, caches = stepper._prefill(
+    first, caches, _ = stepper._prefill(
         stepper._lane_params(), _prompts(stepper.cfg, INSERT_SLOTS, 1))
     pos = jnp.arange(INSERT_SLOTS, dtype=jnp.int32,
                      device=jax.devices()[0])
@@ -498,6 +498,23 @@ def test_lm_insert_writes_one_slot(insert_stepper, slot):
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert g.dtype == w.dtype
         np.testing.assert_array_equal(g, w)
+
+
+def test_lm_init_slots_built_from_shapes(insert_stepper):
+    """``init_slots`` runs no prefill: its state has the shapes and
+    dtypes of a size-8 prefill's caches, holds zeros, and is committed
+    to the lane's device, as the states that later steps return are."""
+    import jax
+
+    st = insert_stepper
+    compiled = st._prefill._cache_size()
+    state = st.init_slots()
+    assert st._prefill._cache_size() == compiled
+    want = _slot_state(st)
+    assert jax.tree.structure(state) == jax.tree.structure(want)
+    for got, w in zip(jax.tree.leaves(state), jax.tree.leaves(want)):
+        assert got.shape == w.shape and got.dtype == w.dtype
+        assert got.committed and not np.any(np.asarray(got))
 
 
 def test_lm_insert_donates_and_compiles_once(insert_stepper):

@@ -79,3 +79,23 @@ def test_opt_state_axes_adafactor_ranks():
                                      sd.shape[-1] > 1 and
                                      sd.shape[-2] > 1) else len(sd.shape)
         assert len(ax) == want
+
+
+@pytest.mark.parametrize("arch_id", [a for a in registry.ARCH_IDS
+                                     if registry.get(a).moe is not None])
+def test_decode_step_caches_match_cache_axes(arch_id):
+    """The dry-run's decode step returns caches of the tree that
+    ``cache_axes`` gives its out_shardings, for the expert models too
+    (their routing counts are not part of the caches)."""
+    from repro.configs.base import ShapeCell
+    from repro.serve.serve_step import make_serve_step
+    cfg = registry.get(arch_id)
+    specs = model_zoo.input_specs(cfg, ShapeCell("t", 64, 2, "decode"),
+                                  tp=1)
+    pvals, _ = model_zoo.param_specs(cfg)
+    _, caches = jax.eval_shape(make_serve_step(cfg), pvals, specs["token"],
+                               specs["caches"], specs["position"])
+    want = jax.tree_util.tree_structure(
+        jax.tree.map(lambda x: 0, sh.cache_axes(cfg), is_leaf=sh.is_axes))
+    assert jax.tree_util.tree_structure(
+        jax.tree.map(lambda x: 0, caches)) == want

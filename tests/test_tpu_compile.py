@@ -29,9 +29,9 @@ from repro.kernels.spmv.spmv import spmv_ell_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2; the persistent compile cache is
-    off meanwhile (a described chip's executables cannot be read back)."""
+def topo():
+    """A described v5e:2x2; the persistent compile cache is off
+    meanwhile (a described chip's executables cannot be read back)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -45,9 +45,15 @@ def one_chip():
         was = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         compilation_cache.reset_cache()
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -115,3 +121,39 @@ def test_conv_tpu_candidates_compile(one_chip, monkeypatch):
                                    col_tile=cfg["col_tile"],
                                    interpret=False)
         jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("rules", [{}, {"expert": "data"}],
+                         ids=["ep", "smap"])
+def test_serving_experts_stay_sharded_on_v5e_mesh(topo, rules):
+    """A serving prefill through DeepSeek-V2-Lite's expert layer at its
+    published widths on a 2 x 2 mesh keeps the expert weights sharded as
+    they are given: no all-gather of a weight (the only arrays with the
+    FFN width, 1408, or its half), with the experts on the model axis
+    ("ep") or on the data axis and the FFN width on the model axis
+    ("smap" rules)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.configs import registry
+    from repro.models import moe
+    from repro.models.param import axes, values
+    from repro.parallel import sharding as ps
+
+    cfg = registry.get("deepseek-v2-lite-16b")
+    tree = jax.eval_shape(lambda: moe.init_moe(jax.random.key(0), cfg))
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    with ps.use_mesh(mesh, overrides=rules):
+        shard = ps.param_shardings(axes(tree), values(tree), mesh)
+        p = jax.tree.map(lambda v, s: jax.ShapeDtypeStruct(
+            v.shape, jnp.bfloat16, sharding=s), values(tree), shard)
+        x = jax.ShapeDtypeStruct(
+            (4, 512, cfg.d_model), jnp.bfloat16, sharding=NamedSharding(
+                mesh, ps.spec_for(("batch", None, None))))
+        hlo = jax.jit(lambda p, x: moe.moe_serve(p, x, cfg)).lower(
+            p, x).compile().as_text()
+    gathered = re.findall(r"= \w+\[([\d,]+)\][^ ]* all-gather\(", hlo)
+    assert not [g for g in gathered
+                if {"1408", "704"} & set(g.split(","))], gathered

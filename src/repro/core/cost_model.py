@@ -37,7 +37,7 @@ from typing import Dict, Optional
 from repro.core.persist import JsonStore, default_calib_path
 
 ENV_DISABLE = "REPRO_COST_MODEL"
-PROFILE_VERSION = 2
+PROFILE_VERSION = 3
 _SECTION = "hardware"
 
 
@@ -129,29 +129,40 @@ def static_profile(device) -> HardwareProfile:
 # Profile measurement + persistence
 # ---------------------------------------------------------------------------
 def _measure_profile(backend: str) -> HardwareProfile:
-    """~100 ms of micro-probes; paid once per backend per store file."""
+    """~100 ms of micro-probes; paid once per backend per store file.
+    Each rate probe's time less the call's dispatch is its work.  On a
+    TPU a call costs about half a millisecond, so its probes are sized
+    to several times that: an 8192 matmul (about 6 ms at the v5e's
+    published 197 TFLOP/s) and a 1 GiB stream (about 2.6 ms at 819
+    GB/s).  Matmuls run at the default precision, so the process's
+    precision setting at first use cannot change the stored rate."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.calibration import measure
 
-    n = 512
-    a = jnp.ones((n, n), jnp.float32)
-    b = jnp.full((n, n), 0.5, jnp.float32)
-    mm = jax.jit(lambda a, b: a @ b)
-    t = measure(lambda: mm(a, b), warmup=2, iters=3, reduce="min")
-    matmul_flops = 2.0 * n ** 3 / max(t, 1e-9)
-
-    m = 1 << 22                                   # 16 MB f32: past cache
-    x = jnp.ones((m,), jnp.float32)
-    ew = jax.jit(lambda x: x * 1.0000001 + 0.5)
-    t = measure(lambda: ew(x), warmup=2, iters=3, reduce="min")
-    ew_flops = 2.0 * m / max(t, 1e-9)
-    mem_bw = 8.0 * m / max(t, 1e-9)               # read + write
-
     tiny = jnp.ones((8,), jnp.float32)
     f = jax.jit(lambda x: x + 1.0)
     dispatch_s = measure(lambda: f(tiny), warmup=3, iters=10, reduce="min")
+
+    def work_s(fn):
+        t = measure(fn, warmup=2, iters=3, reduce="min")
+        return max(t - dispatch_s, 1e-9)
+
+    tpu = backend == "tpu"
+    n = 8192 if tpu else 512
+    a = jnp.ones((n, n), jnp.float32)
+    b = jnp.full((n, n), 0.5, jnp.float32)
+    mm = jax.jit(lambda a, b: a @ b)
+    with jax.default_matmul_precision("default"):
+        matmul_flops = 2.0 * n ** 3 / work_s(lambda: mm(a, b))
+
+    m = 1 << 28 if tpu else 1 << 22               # f32: past cache
+    x = jnp.ones((m,), jnp.float32)
+    ew = jax.jit(lambda x: x * 1.0000001 + 0.5)
+    t = work_s(lambda: ew(x))
+    ew_flops = 2.0 * m / t
+    mem_bw = 8.0 * m / t                          # read + write
 
     h = 1 << 18                                   # 1 MB through a callback
     xs = jnp.ones((h,), jnp.float32)

@@ -13,17 +13,22 @@ as using the wrong scheduling granularity.  This engine makes one
   nothing reads, which is what keeps join/evict bit-identical to solo
   decode (vmap rows are independent);
 * new same-bucket arrivals join the running batch at the next step
-  boundary (their prefill runs on a separate lane, see below) instead
+  boundary (their prefill runs beside the step loop, see below) instead
   of waiting for the batch to drain;
 * finished rows are evicted at the boundary and their outputs demuxed
   exactly per request.
 
-**Prefill/decode disaggregation** (paper §5.4.3 suitability split):
-compute-bound prefill runs as a dedicated unit on the projected-fastest
-lane while the bandwidth-bound step-loop is co-scheduled on the other
-lane — the Scheduler picks both lanes from ``CostTerms`` priors
-(``cost_model.lm_prefill_terms``/``lm_decode_terms``) scaled by group
-slowdown, so a fresh process places with zero probe runs.
+**Prefill and decode placed by cost** (paper §5.4.3 suitability
+split): compute-bound prefill runs as a dedicated unit on the
+projected-fastest lane, and the bandwidth-bound step loop runs on the
+lane where a step costs least — the prefill lane itself when one decode
+plus one prefill there beats every other lane's decode
+(``placement.plan_disaggregation``).  The Scheduler prices both from
+``CostTerms`` priors (``cost_model.lm_prefill_terms``/
+``lm_decode_terms``), so a fresh process places with zero probe runs.
+When the two phases take a common lane lock, the step loop runs the
+prefills itself, at its boundaries and only for free slots
+(``ContinuousEngine``).
 
 The same mechanism generalizes past LMs: any sequential workload whose
 unit of progress is "one iteration over carried state" (listrank
@@ -112,11 +117,28 @@ class ContinuousEngine:
     Two threads: ``serve-cb-<wl>-prefill`` turns submissions into slot
     rows on the prefill lane; ``serve-cb-<wl>-step`` runs the batched
     step loop on the decode lane, joining ready rows and evicting
-    finished ones at every step boundary.  Lane locks are acquired
-    per-phase and *released between steps* — that release IS the
-    preemption point: any dedicated/shared work the Scheduler placed on
-    the same lane interleaves at iteration boundaries instead of
+    finished ones at every step boundary; where the two phases' lane
+    locks overlap, the step thread does both (below).  Lane locks are
+    acquired per-phase and *released between steps* — that release IS
+    the preemption point: any dedicated/shared work the Scheduler placed
+    on the same lane interleaves at iteration boundaries instead of
     waiting for a whole request.
+
+    When ``prefill_locks`` and ``step_locks`` share a lock (one lane
+    for both phases, or a simulated platform whose lanes all take
+    every lock), two threads would race for it at every boundary.
+    There the step loop runs the prefills itself: at each boundary,
+    after its evictions, it prefills queued requests one at a time
+    while fewer rows are ready than slots are free, and their rows
+    join at once.  The batch stalls for at most the prefills that
+    refill its free slots, and a burst of arrivals cannot put more
+    prefills between two steps.  ``prefill_yields`` counts the times
+    the step loop stopped between two of its steps to run prefills,
+    at a boundary with rows live or with none live (a batch whose rows
+    all finished at one step, or an idle engine); ``shared_prefills``
+    counts the prefills run so.  On separate locks prefills run ahead
+    of free slots on their own thread, as they cost the batch nothing
+    there.
 
     ``resolve(req, value, t_start)`` is the Scheduler's ``_resolve``
     (keeps the accounting invariant: every submitted request is
@@ -132,12 +154,13 @@ class ContinuousEngine:
     joined row's ``engine_insert`` and the ``decode`` call, on the
     ``lane:<group>`` track of the lane that ran them.  Every child of
     step ``k`` carries ``step=k``; ``engine_boundary`` covers the host
-    work between two steps.  For a model with routed experts,
-    ``prefill_call`` also carries ``expert_load_max`` and ``decode``
-    ``experts_hit`` (``LMStepper.route_args``).  While the recorder is
-    on, ``init_slots``, each insert and each step wait for their outputs
-    before their spans close, so the spans time the work and not its
-    dispatch.
+    work between two steps and, where its ``yielded_to_prefill`` arg is
+    true, the prefills the step loop ran there.  For a model with
+    routed experts, ``prefill_call`` also carries ``expert_load_max``
+    and ``decode`` ``experts_hit`` (``LMStepper.route_args``).  While
+    the recorder is on, ``init_slots``, each insert and each step wait
+    for their outputs before their spans close, so the spans time the
+    work and not its dispatch.
 
     ``should_yield()`` (optional) is polled at every step boundary:
     while it returns True — the Scheduler dispatched latency-class
@@ -191,11 +214,16 @@ class ContinuousEngine:
         self._free: List[int] = list(range(self.n_slots))[::-1]
         self._live: Dict[int, _Row] = {}
         self._stop = False
+        # the phases take a common lock: the step loop prefills too
+        self._shared = bool(set(self.prefill_locks) & set(self.step_locks))
+        self._prefilling = False        # a request taken, rows not ready
         self.steps = 0
         self.joins = 0
         self.evictions = 0
         self.cancellations = 0
         self.preemptions = 0
+        self.prefill_yields = 0
+        self.shared_prefills = 0
         self.max_live = 0
         self.init_s: Optional[float] = None
         traced = self._rec.enabled
@@ -215,12 +243,13 @@ class ContinuousEngine:
         finally:
             for lk in reversed(self.step_locks):
                 lk.release()
+        loops = {"step": self._step_loop}
+        if not self._shared:
+            loops["prefill"] = self._prefill_loop
         self._threads = [
-            threading.Thread(target=self._prefill_loop, daemon=True,
-                             name=f"serve-cb-{_safe(self.workload)}-prefill"),
-            threading.Thread(target=self._step_loop, daemon=True,
-                             name=f"serve-cb-{_safe(self.workload)}-step"),
-        ]
+            threading.Thread(target=fn, daemon=True,
+                             name=f"serve-cb-{_safe(self.workload)}-{name}")
+            for name, fn in sorted(loops.items())]
         for t in self._threads:
             t.start()
         _LIVE.add(self)
@@ -237,48 +266,76 @@ class ContinuousEngine:
 
     # ---- prefill lane ----------------------------------------------------
     def _prefill_loop(self) -> None:
-        rec = self._rec
+        """Separate locks: each request is prefilled as it arrives."""
         while True:
             with self._cv:
-                while not self._inbox and not self._stop:
+                while not self._inbox:
+                    if self._stop:
+                        return
                     self._cv.wait()
-                if self._stop and not self._inbox:
-                    return
-                pending = self._inbox.popleft()
-            trace_id = getattr(pending.req, "trace_id", None)
+                pending = self._take()
+            self._prefill_rows(pending)
+
+    def _prefill_for_free_slots(self) -> int:
+        """A common lock: the step loop's own prefills, one request at
+        a time while fewer rows are ready than slots are free.  Returns
+        how many ran."""
+        n = 0
+        while True:
+            with self._cv:
+                if not self._inbox or len(self._ready) >= len(self._free):
+                    return n
+                pending = self._take()
+                self.shared_prefills += 1
+            self._prefill_rows(pending)
+            n += 1
+
+    def _take(self) -> _Pending:
+        """The next request for prefill (under ``_cv``)."""
+        self._prefilling = True
+        return self._inbox.popleft()
+
+    def _prefill_rows(self, pending: _Pending) -> None:
+        """Prefill a taken request and queue its rows as ready."""
+        try:
+            rows = self._prefill(pending)
+        except BaseException as exc:              # noqa: BLE001
+            rows = []
+            self._reject(pending.req, exc)
+        with self._cv:
+            for i, (row_state, first_out, n_steps) in enumerate(rows):
+                row = _Row(pending, i, first_out, n_steps)
+                self._ready.append((row, row_state))
+            self._prefilling = False
+            self._cv.notify_all()
+
+    def _prefill(self, pending: _Pending) -> list:
+        """One request's prefill on the prefill lane; its rows."""
+        rec = self._rec
+        trace_id = getattr(pending.req, "trace_id", None)
+        with rec.span("prefill", "engine", self._track, trace_id,
+                      workload=self.workload, group=self.prefill_group):
+            with rec.span("lane_wait", "engine", self._track, trace_id,
+                          phase="prefill", group=self.prefill_group):
+                for lk in self.prefill_locks:
+                    lk.acquire()
             try:
-                with rec.span("prefill", "engine", self._track, trace_id,
-                              workload=self.workload,
-                              group=self.prefill_group):
-                    with rec.span("lane_wait", "engine", self._track,
-                                  trace_id, phase="prefill",
-                                  group=self.prefill_group):
-                        for lk in self.prefill_locks:
-                            lk.acquire()
-                    try:
-                        with self._prefill_ctx(), self._lane_span(
-                                "prefill_call", self.prefill_group,
-                                trace_id) as args:
-                            rows = self.stepper.prefill(pending.spec)
-                            args["rows"] = len(rows)
-                            if rec.enabled:
-                                args.update(self._route_args("prefill"))
-                    finally:
-                        for lk in reversed(self.prefill_locks):
-                            lk.release()
-                pending.req.future.meta.setdefault(
-                    "t_first_token", self._clock())
-                pending.req.future.meta.setdefault("engine", {
-                    "prefill_group": self.prefill_group,
-                    "decode_group": self.decode_group})
-                pending.n_rows = len(rows)
-                with self._cv:
-                    for i, (row_state, first_out, n_steps) in enumerate(rows):
-                        row = _Row(pending, i, first_out, n_steps)
-                        self._ready.append((row, row_state))
-                    self._cv.notify_all()
-            except BaseException as exc:          # noqa: BLE001
-                self._reject(pending.req, exc)
+                with self._prefill_ctx(), self._lane_span(
+                        "prefill_call", self.prefill_group,
+                        trace_id) as args:
+                    rows = self.stepper.prefill(pending.spec)
+                    args["rows"] = len(rows)
+                    if rec.enabled:
+                        args.update(self._route_args("prefill"))
+            finally:
+                for lk in reversed(self.prefill_locks):
+                    lk.release()
+        pending.req.future.meta.setdefault("t_first_token", self._clock())
+        pending.req.future.meta.setdefault("engine", {
+            "prefill_group": self.prefill_group,
+            "decode_group": self.decode_group})
+        pending.n_rows = len(rows)
+        return rows
 
     @contextmanager
     def _lane_span(self, name: str, group: str,
@@ -299,13 +356,17 @@ class ContinuousEngine:
         joined: List[tuple] = []
         while True:
             if not live_now:
-                # nothing live: wait for arrivals, outside any span
+                # nothing live: wait for arrivals, outside any span; a
+                # stopped engine still serves what it was given
                 with self._cv:
-                    while (not self._ready and not self._live
-                           and not self._stop):
+                    while not (self._ready or self._live
+                               or (self._shared and self._inbox)):
+                        if (self._stop and not self._inbox
+                                and not self._prefilling):
+                            return
                         self._cv.wait()
-                    if self._stop and not self._ready and not self._live:
-                        return
+                if self._shared and self._prefill_for_free_slots():
+                    self.prefill_yields += 1
                 live_now, joined = self._join()
                 if not live_now:
                     continue
@@ -319,6 +380,10 @@ class ContinuousEngine:
                 if "on_step" in self._hooks:
                     self._hooks["on_step"](len(live_now))
                 args["evicted"] = self._retire(live_now, outs)
+                yielded = self._shared and self._prefill_for_free_slots() > 0
+                if yielded:
+                    self.prefill_yields += 1
+                args["yielded_to_prefill"] = yielded
                 live_now, joined = self._join()
                 args["joined_next"] = len(joined)
 
@@ -502,7 +567,8 @@ class ContinuousEngine:
         """Block until no work is queued or live (tests/benchmarks)."""
         deadline = self._clock() + timeout
         with self._cv:
-            while (self._inbox or self._ready or self._live):
+            while (self._inbox or self._prefilling or self._ready
+                   or self._live):
                 remaining = deadline - self._clock()
                 if remaining <= 0:
                     return False
@@ -527,6 +593,8 @@ class ContinuousEngine:
                     "joins": self.joins, "evictions": self.evictions,
                     "cancellations": self.cancellations,
                     "preemptions": self.preemptions,
+                    "prefill_yields": self.prefill_yields,
+                    "shared_prefills": self.shared_prefills,
                     "max_live": self.max_live, "live": len(self._live),
                     "prefill_group": self.prefill_group,
                     "decode_group": self.decode_group}

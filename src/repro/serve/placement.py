@@ -164,7 +164,10 @@ def plan_placement(n_units: int, groups: List[GroupLoad], now: float,
 class DisaggregationPlan:
     """Phase-to-lane assignment for a two-phase workload (the paper's
     §5.4.3 suitability split applied to LM serving): compute-bound
-    prefill on one lane, bandwidth-bound decode on another."""
+    prefill on the fastest lane, the bandwidth-bound decode step on the
+    lane where a step costs least.  ``disaggregated`` when the two
+    differ; when they share a lane, each step there may wait for one
+    prefill (``continuous.ContinuousEngine`` orders the two)."""
     prefill_group: str
     decode_group: str
     est_prefill_s: float
@@ -182,26 +185,36 @@ def plan_disaggregation(groups: List[GroupLoad],
                         ) -> Optional[DisaggregationPlan]:
     """Assign prefill and decode lanes from per-group phase estimates.
 
-    Prefill goes to the group with the smallest projected prefill time
-    (it is compute-bound, so this is the fastest-matmul lane); the
-    decode step-loop is co-scheduled on the best *other* lane so new
-    arrivals' prefills never stall the running batch.  With one alive
-    group both phases share it.  Pure function over plain estimates —
-    the scheduler resolves ``prefill_times``/``decode_times`` from
-    ``CostTerms`` priors scaled by group slowdown, so a fresh process
+    Prefill goes to the alive group with the smallest projected prefill
+    time (it is compute-bound, so this is the fastest-matmul lane).
+    Decode goes to the alive group with the smallest projected time per
+    step: a decode step on another lane costs its decode time; on the
+    prefill lane itself it costs one decode plus one prefill, the worst
+    case, in which a joining row's prefill takes the lane at every step
+    boundary.  So decode shares the prefill lane only where that sum
+    still beats every other lane's decode, and with one alive group
+    both phases share it.  ``alternatives`` holds every score, the
+    shared lane's as ``decode+prefill:<group>``.  Pure function over
+    plain estimates — the scheduler resolves ``prefill_times``/
+    ``decode_times`` from ``CostTerms`` priors, so a fresh process
     places with zero probe runs."""
     alive = [g for g in groups if g.alive]
     if not alive:
         return None
     inf = float("inf")
     pre = min(alive, key=lambda g: prefill_times.get(g.name, inf))
-    others = [g for g in alive if g.name != pre.name]
-    dec = (min(others, key=lambda g: decode_times.get(g.name, inf))
-           if others else pre)
+    shared = (decode_times.get(pre.name, inf)
+              + prefill_times.get(pre.name, inf))
+
+    def step_time(g: GroupLoad) -> float:
+        return shared if g is pre else decode_times.get(g.name, inf)
+
+    dec = min(alive, key=step_time)
     scores = {f"prefill:{g.name}": prefill_times.get(g.name, inf)
               for g in alive}
     scores.update({f"decode:{g.name}": decode_times.get(g.name, inf)
                    for g in alive})
+    scores[f"decode+prefill:{pre.name}"] = shared
     return DisaggregationPlan(
         pre.name, dec.name,
         est_prefill_s=prefill_times.get(pre.name, 0.0),

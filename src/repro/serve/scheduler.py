@@ -36,8 +36,8 @@ zero probes, PR 3's cold-start contract).
 Adapters whose spec carries a ``stepper`` additionally route through
 the **continuous-batching engine** (``serve/continuous.py``): the
 decode step becomes the scheduling quantum, live requests stack into
-one slot-batched kernel call per step, and prefill/decode are
-disaggregated across lanes from ``CostTerms`` priors
+one slot-batched kernel call per step, and prefill and decode are
+placed by cost from ``CostTerms`` priors, on one lane or on two
 (``placement.plan_disaggregation`` — zero probes on a cold start).
 ``REPRO_SERVE_CONTINUOUS=0`` disables the route: stepper specs fall
 back to their monolithic ``run_one`` path.
@@ -1013,19 +1013,28 @@ class Scheduler:
         """Phase-to-lane assignment from CostTerms priors only (no
         probes: a fresh process must place with last_probe_runs == 0).
         Prefill is compute-bound, decode bandwidth-bound — predict()
-        rates them against the measured backend profile, scaled by
-        each group's slowdown, on the profile of that group's device.
-        None when no lane is alive (caller
-        delivers a structured rejection)."""
+        rates each against the measured profile of the group's device;
+        ``plan_disaggregation`` then puts decode where a step costs
+        least, on the prefill lane at the price of one decode plus one
+        prefill.  A simulated group's ``slowdown`` is left out: it
+        stretches the executor's and the scheduler's measured times,
+        while the engine's calls run unstretched on the device, so
+        groups on one device cost the same (and the phases stay on two
+        groups, since sharing would add the prefill; both groups take
+        every lock there, so the engine still runs them in order on one
+        thread).  None when no lane is alive (caller delivers a
+        structured rejection)."""
         from repro.core import cost_model
         with self._lock:
             loads = [GroupLoad(ld.name, None, ld.busy_until, ld.alive)
                      for ld in self._loads.values()]
+
         def prior(cost, g):
-            # with the model off, lanes rank by their slowdown alone
+            # with the model off nothing is priced: every lane costs
+            # the same, and the phases stay on different lanes
             if not cost_model.enabled():
-                return g.slowdown
-            return cost_model.predict(cost, g.device) * g.slowdown
+                return 1.0
+            return cost_model.predict(cost, g.device)
 
         pre = {g.name: prior(stepper.prefill_cost, g) for g in self.groups}
         dec = {g.name: prior(stepper.decode_cost, g) for g in self.groups}

@@ -61,6 +61,29 @@ def test_profile_measured_and_persisted(stores):
     assert p2.matmul_flops == pytest.approx(p.matmul_flops)
 
 
+def test_profile_rates_leave_out_the_dispatch(monkeypatch):
+    """A device whose every call costs 0.5 ms of dispatch on top of
+    its work (a TPU's) reads its own matmul and stream rates, not the
+    dispatch: a probe's time less the dispatch is its work."""
+    from repro.core import calibration
+
+    dispatch, flops, bw = 0.5e-3, 1e14, 8e11
+
+    def fake_measure(fn, warmup=1, iters=3, reduce="mean"):
+        out = jax.block_until_ready(fn())
+        if out.shape == (8,):
+            return dispatch
+        if out.ndim == 2:                          # the n x n matmul
+            return dispatch + 2.0 * out.shape[0] ** 3 / flops
+        return dispatch + 8.0 * out.size / bw      # a stream, read+write
+
+    monkeypatch.setattr(calibration, "measure", fake_measure)
+    p = cost_model._measure_profile("cpu")
+    assert p.dispatch_s == pytest.approx(dispatch)
+    assert p.matmul_flops == pytest.approx(flops)
+    assert p.mem_bw == pytest.approx(bw)
+
+
 def test_profile_static_fallback_when_disabled(stores, monkeypatch):
     """With the model off, only a v5e gets the static profile; any
     other device raises instead of being priced as a v5e."""

@@ -157,3 +157,50 @@ def test_serving_experts_stay_sharded_on_v5e_mesh(topo, rules):
     gathered = re.findall(r"= \w+\[([\d,]+)\][^ ]* all-gather\(", hlo)
     assert not [g for g in gathered
                 if {"1408", "704"} & set(g.split(","))], gathered
+
+
+@pytest.mark.parametrize("arch,dtype,precision,cache_len", [
+    ("xlstm-350m", jnp.float32, "highest", 1024 + 32 + 1),
+    ("deepseek-v2-lite-16b", jnp.bfloat16, None, 1536 + 12 + 1),
+])
+def test_slot_step_compiles_for_v5e(one_chip, arch, dtype, precision,
+                                    cache_len):
+    """The continuous engine's decode step, ``make_slot_step`` over 8
+    slots, compiles for one v5e chip at the model's registry widths and
+    its benchmark cell's weights, precision and cache length, cut to two
+    layers: one mLSTM and one sLSTM block, or the dense layer and one
+    expert layer.  It returns the slot state it was given, shape for
+    shape."""
+    import dataclasses
+
+    from repro.configs import registry
+    from repro.models import model_zoo, param
+    from repro.serve.serve_step import make_slot_step
+
+    slots = 8
+    cfg = registry.get(arch).replace(n_layers=2)
+    if cfg.block_pattern == "xlstm":
+        cfg = cfg.replace(xlstm=dataclasses.replace(cfg.xlstm,
+                                                    slstm_every=2))
+
+    def on_chip(a, dt=None):
+        return _spec(one_chip, a.shape, dt or a.dtype)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a, dtype if jnp.issubdtype(a.dtype, jnp.floating)
+                          else None),
+        jax.eval_shape(lambda: param.values(
+            model_zoo.init(cfg, jax.random.key(0)))))
+    _, caches = jax.eval_shape(
+        lambda p, t: model_zoo.prefill(cfg, p, {"tokens": t},
+                                       cache_len=cache_len),
+        params, jax.ShapeDtypeStruct((slots, 16), jnp.int32))
+    caches = jax.tree.map(on_chip, caches)
+    tokens = _spec(one_chip, (slots,), jnp.int32)
+    step = make_slot_step(cfg)
+    with jax.default_matmul_precision(precision):
+        step.lower(params, tokens, caches, tokens).compile()
+        _, out, _ = jax.eval_shape(step, params, tokens, caches, tokens)
+    assert jax.tree.structure(out) == jax.tree.structure(caches)
+    for got, want in zip(jax.tree.leaves(out), jax.tree.leaves(caches)):
+        assert (got.shape, got.dtype) == (want.shape, want.dtype)
